@@ -89,13 +89,11 @@ def moe_bench_config(d: int) -> ModelConfig:
 def _onehot_matmul(x, values, indices, n, m, b, idx_bits=8):
     """The pre-rework ref formulation: fp32 one-hot expansion — O(m/keep)×
     extra FLOPs and a (c, g, keep, m) fp32 intermediate.  Benchmark-only."""
-    keep = m - n
-    c = values.shape[0]
-    g = b // m
     if idx_bits == 4:
-        indices = unpack_indices4(indices, g * keep)
-    vals = values.reshape(c, g, keep).astype(jnp.float32)
-    idx = indices.reshape(c, g, keep).astype(jnp.int32)
+        indices = unpack_indices4(indices, m - n)
+    vals = jnp.moveaxis(values, 0, -1).astype(jnp.float32)   # (c, g, keep)
+    idx = jnp.moveaxis(indices, 0, -1).astype(jnp.int32)
+    c = vals.shape[0]
     onehot = idx[..., None] == jnp.arange(m)[None, None, None, :]
     dense = jnp.sum(vals[..., None] * onehot, axis=2).reshape(c, b)
     return (x.astype(jnp.float32) @ dense.T).astype(x.dtype)

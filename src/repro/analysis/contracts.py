@@ -256,7 +256,8 @@ def _check_reduced_decodes(arch: str) -> list[Finding]:
 def _check_pspecs(arch: str, a_params, a_cache) -> list[Finding]:
     from repro.dist import sharding as D
 
-    mesh = AbstractMesh(_MESH)
+    mesh = AbstractMesh(tuple(n for _, n in _MESH),
+                        tuple(a for a, _ in _MESH))
     out: list[Finding] = []
 
     def check(tree, specs, what: str):

@@ -7,6 +7,14 @@ positions packed per int8 byte (the default), 2:4 bf16 costs
 2×2 bytes values + 1 byte packed indices per 8 bytes dense = 62.5% of dense
 bytes (50% + index overhead); for fp32 it is 56.25%.
 
+Storage is **slot-major**: with g = b/m groups per row and keep = m−n
+kept values per group, ``values`` is (keep, c, g) — plane k holds the k-th
+kept value (ascending in-group position) of every group — and the indices
+are the matching (keep, c, g) in-group positions, two slots per byte
+(slot 2p low nibble, slot 2p+1 high nibble) for 4-bit storage.  Every
+plane is a lane-aligned (c, g) matrix, which is what lets the Pallas kernel
+expand tiles without in-kernel reshapes (kernels/nm_spmm.py).
+
 ``NmCompressed`` is the on-disk/LHS format consumed by
 ``kernels/nm_spmm.py`` and the serving decode path.
 """
@@ -29,6 +37,14 @@ Array = jax.Array
 NON_STREAMABLE_KERNELS = frozenset({"wkv_b"})
 
 
+def nm_storage_shapes(c: int, b: int, n: int, m: int,
+                      idx_bits: int = 4) -> tuple[tuple, tuple]:
+    """(values, indices) shapes of one packed (c, b) n:m matrix."""
+    keep, g = m - n, b // m
+    planes = (keep + 1) // 2 if idx_bits == 4 else keep
+    return (keep, c, g), (planes, c, g)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class NmCompressed:
@@ -39,14 +55,14 @@ class NmCompressed:
     traced.
 
     ``idx_bits`` selects the index storage: 8 = one in-group position per
-    int8 byte (the debugging-friendly layout); 4 = two positions per byte,
+    int8 byte (the debugging-friendly layout); 4 = two slots per byte,
     low nibble first (the serving layout — requires m ≤ 16).
     """
 
-    values: Array    # (c, b // m * (m-n)) kept weights, group-major
-    indices: Array   # int8 in-group positions; (c, b//m*(m-n)) for
-                     # idx_bits=8, (c, ceil(b//m*(m-n)/2)) nibble-packed
-                     # for idx_bits=4
+    values: Array    # (keep, c, b // m) kept weights, slot-major
+    indices: Array   # int8 in-group positions; (keep, c, b // m) for
+                     # idx_bits=8, (ceil(keep/2), c, b // m) nibble-packed
+                     # slot pairs for idx_bits=4
     n: int
     m: int
     b: int           # original column count
@@ -57,10 +73,9 @@ class NmCompressed:
         return self.m - self.n
 
     def unpacked_indices(self) -> Array:
-        """int8 (c, g·keep) in-group positions regardless of idx_bits."""
-        length = (self.b // self.m) * self.kept_per_group
+        """int8 (keep, c, g) in-group positions regardless of idx_bits."""
         if self.idx_bits == 4:
-            return unpack_indices4(self.indices, length)
+            return unpack_indices4(self.indices, self.kept_per_group)
         return self.indices
 
     def tree_flatten(self):
@@ -73,26 +88,28 @@ class NmCompressed:
 
 
 def pack_indices4(idx: Array) -> Array:
-    """Pack int8 in-group positions (c, L), values ∈ [0, 16), two per byte.
+    """Pack int8 slot planes (keep, ...), values ∈ [0, 16), two per byte.
 
-    Byte t holds entries 2t (low nibble) and 2t+1 (high nibble); an odd L is
-    zero-padded into the final high nibble.  → (c, ⌈L/2⌉) int8.
+    Byte plane p holds slot 2p (low nibble) and slot 2p+1 (high nibble); an
+    odd ``keep`` leaves the final high nibble zero.  → (⌈keep/2⌉, ...) int8,
+    every plane keeping the (c, g) shape of a value plane.
     """
-    c, L = idx.shape
-    if L % 2:
-        idx = jnp.pad(idx, ((0, 0), (0, 1)))
-    u = idx.astype(jnp.uint8).reshape(c, -1, 2)
-    return (u[..., 0] | (u[..., 1] << 4)).astype(jnp.int8)
+    u = idx.astype(jnp.uint8)
+    lo = u[0::2]
+    hi = u[1::2]
+    if hi.shape[0] < lo.shape[0]:
+        hi = jnp.concatenate([hi, jnp.zeros_like(lo[:1])], axis=0)
+    return (lo | (hi << 4)).astype(jnp.int8)
 
 
-def unpack_indices4(packed: Array, length: int) -> Array:
-    """Inverse of pack_indices4 — (c, ⌈L/2⌉) bytes → (c, ``length``) int8."""
-    c = packed.shape[0]
+def unpack_indices4(packed: Array, keep: int) -> Array:
+    """Inverse of pack_indices4 — (⌈keep/2⌉, ...) bytes → (keep, ...) int8."""
     raw = packed.astype(jnp.int32)            # sign-extends; masked below
     lo = raw & 0xF
     hi = (raw >> 4) & 0xF
-    both = jnp.stack([lo, hi], axis=-1).reshape(c, -1)
-    return both[:, :length].astype(jnp.int8)
+    both = jnp.stack([lo, hi], axis=1)        # (planes, 2, ...)
+    both = both.reshape((-1,) + packed.shape[1:])
+    return both[:keep].astype(jnp.int8)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -112,9 +129,9 @@ class NmStackedCompressed:
     data; only ``values``/``indices`` are traced.
     """
 
-    values: Array    # (E, c, b // m * (m-n)) kept weights, group-major
-    indices: Array   # int8 in-group positions; (E, c, g·keep) for
-                     # idx_bits=8, (E, c, ⌈g·keep/2⌉) nibble-packed for 4
+    values: Array    # (E, keep, c, b // m) kept weights, slot-major
+    indices: Array   # int8 in-group positions; (E, keep, c, g) for
+                     # idx_bits=8, (E, ⌈keep/2⌉, c, g) nibble-packed for 4
     n: int
     m: int
     b: int           # original column count (per expert)
@@ -126,10 +143,10 @@ class NmStackedCompressed:
         return self.m - self.n
 
     def unpacked_indices(self) -> Array:
-        """int8 (E, c, g·keep) in-group positions regardless of idx_bits."""
-        length = (self.b // self.m) * self.kept_per_group
+        """int8 (E, keep, c, g) in-group positions regardless of idx_bits."""
         if self.idx_bits == 4:
-            return jax.vmap(lambda i: unpack_indices4(i, length))(self.indices)
+            keep = self.kept_per_group
+            return jax.vmap(lambda i: unpack_indices4(i, keep))(self.indices)
         return self.indices
 
     def tree_flatten(self):
@@ -147,7 +164,8 @@ def pack_nm(w: Array, mask: Array, n: int, m: int, *,
 
     Every m-group must contain exactly n ones in ``mask``; validated by
     tests (core.masks.check_nm) rather than at trace time.  Kept positions
-    are stored in ascending in-group order.
+    are stored in ascending in-group order, slot k of every group in value
+    plane k (slot-major, see the module docstring).
     """
     assert idx_bits in (4, 8), idx_bits
     assert idx_bits == 8 or m <= 16, f"4-bit indices need m ≤ 16, got {m}"
@@ -159,9 +177,9 @@ def pack_nm(w: Array, mask: Array, n: int, m: int, *,
     key = jnp.where(mk, jnp.arange(m)[None, None, :], m + jnp.arange(m)[None, None, :])
     order = jnp.argsort(key, axis=-1)[..., :keep]          # (c, g, keep)
     vals = jnp.take_along_axis(w.reshape(c, g, m), order, axis=-1)
-    idx8 = order.astype(jnp.int8).reshape(c, g * keep)
+    idx8 = jnp.moveaxis(order.astype(jnp.int8), -1, 0)     # (keep, c, g)
     return NmCompressed(
-        values=vals.reshape(c, g * keep),
+        values=jnp.moveaxis(vals, -1, 0),
         indices=pack_indices4(idx8) if idx_bits == 4 else idx8,
         n=n, m=m, b=b, idx_bits=idx_bits,
     )
@@ -173,11 +191,10 @@ def unpack_nm(packed: NmCompressed) -> Array:
     A gather-free in-group scatter: each kept value lands at its stored
     position, untouched positions stay zero (no fp32 one-hot contraction).
     """
-    c = packed.values.shape[0]
-    keep = packed.kept_per_group
+    c = packed.values.shape[1]
     g = packed.b // packed.m
-    vals = packed.values.reshape(c, g, keep)
-    idx = packed.unpacked_indices().reshape(c, g, keep).astype(jnp.int32)
+    vals = jnp.moveaxis(packed.values, 0, -1)                # (c, g, keep)
+    idx = jnp.moveaxis(packed.unpacked_indices(), 0, -1).astype(jnp.int32)
     dense = jnp.zeros((c, g, packed.m), packed.values.dtype)
     dense = dense.at[
         jnp.arange(c)[:, None, None], jnp.arange(g)[None, :, None], idx

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.api import PruneConfig, prune_layer
@@ -78,8 +77,14 @@ def prune_layer_sharded(
     untouched (zero mask, zero loss) without entering the shard_map.
 
     Bit-exact with single-device ``prune_layer`` on a 1×1 mesh for every
-    method and pattern; n:m/structured masks stay bit-exact at any shard
-    count (weights to float-reassociation tolerance).
+    method and pattern; on the CPU n:m/structured masks stay bit-exact at
+    any shard count (weights to float-reassociation tolerance).  On a v5e
+    at h2o-danube-1.8b's widths each shard's result equals the single-
+    device ``prune_layer`` of that shard's rows bit for bit, while the
+    single-device prune of all rows, a program compiled for another row
+    count, does not: its fp32 results differ in the last bits, and
+    near-tie n:m choices can fall the other way (``chip_smoke.py
+    --four-chips``).
     """
     if isinstance(cfg, PrunePlan):
         if cfg.allocation is not None:
@@ -111,12 +116,12 @@ def prune_layer_sharded(
         loss = jax.lax.psum(res.loss, axes) if axes else res.loss
         return PruneResult(res.weights, res.mask, loss)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(rows, P(None, None)),
         out_specs=PruneResult(weights=rows, mask=rows, loss=P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(w, h_arg)
 
@@ -149,7 +154,7 @@ def hessian_all_reduce(acc, mesh: Mesh, axes: tuple[str, ...] = ("data",)):
                                   acc.skipped.sum(0))
 
     rep = P(_entry(axes))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda a: HessianAccumulator(
             jax.lax.psum(a.xtx[0], axes), jax.lax.psum(a.count[0], axes),
             jax.lax.psum(a.skipped[0], axes)),
@@ -158,6 +163,6 @@ def hessian_all_reduce(acc, mesh: Mesh, axes: tuple[str, ...] = ("data",)):
             xtx=P(_entry(axes), None, None), count=rep, skipped=rep),),
         out_specs=HessianAccumulator(xtx=P(None, None), count=P(),
                                      skipped=P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(acc)

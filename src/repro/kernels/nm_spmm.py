@@ -5,31 +5,35 @@ have no sparse MXU, so the transferable win is **HBM traffic** (DESIGN.md
 §3): decode is memory-bound (arithmetic intensity ≈ batch), and the weight
 stream dominates bytes.  This kernel streams the *compressed* representation
 HBM→VMEM — ``keep/m`` of the dense values plus nibble-packed 4-bit in-group
-indices — expands each tile to dense **inside VMEM** with an in-group
-scatter (VPU), and feeds the dense tile to the MXU.  Compute term
-unchanged; memory term scales by ≈ (keep/m + index overhead).
+indices — expands each tile to dense **inside VMEM** with per-slot selects
+(VPU), and feeds the dense planes to the MXU.  Compute term unchanged;
+memory term scales by ≈ (keep/m + index overhead).
 
-Layout (group-major, g = b/m groups, keep = m−n kept values per group):
-    values  (c, g·keep)   same dtype as x
-    indices idx_bits=8 → (c, g·keep) int8, in-group position ∈ [0, m)
-            idx_bits=4 → (c, ⌈g·keep/2⌉) int8, two positions per byte
-                         (low nibble first — core/sparsity.pack_indices4)
+Layout (slot-major, g = b/m groups, keep = m−n kept values per group;
+owned by core/sparsity.pack_nm):
+    values  (keep, c, g)  plane k = the k-th kept value of every group
+    indices idx_bits=8 → (keep, c, g) int8 in-group positions ∈ [0, m)
+            idx_bits=4 → (⌈keep/2⌉, c, g) int8: plane p holds slot 2p in
+                         the low nibble and slot 2p+1 in the high nibble
 
-The VMEM expansion is a per-kept-slot select-accumulate: for each of the
-``keep`` static slots, values are placed where the (ct, gt, m) iota matches
-the slot's index.  Peak VMEM is one (ct, gt, m) fp32 tile — the old one-hot
-contraction materialized a (ct, gt, keep, m) fp32 tensor (keep× the VMEM)
-and spent m/keep× extra fp32 multiply-adds for the same placement.
+Every operand plane is a lane-aligned (c, g) matrix, so the kernel never
+reshapes across lanes.  The activations arrive split into their m strided
+planes ``x_j = x[:, j::m]`` (m, B, g) — a cheap XLA transpose at decode
+batch sizes — and the product is
 
-Grid: (x_tiles, c_tiles, b_tiles) — b is the contraction dim, accumulated in
-a fp32 VMEM scratch; the output tile is written once on the last b step
-(standard Pallas accumulation pattern).  Tile defaults are MXU-aligned
-(lane = 128 multiples).  With idx_bits=4 and more than one b tile, the
-compressed tile width (block_b//m·keep) must be even so index tiles fall on
-byte boundaries — kernels/ops.choose_tiles guarantees this.
+    y = Σ_j x_j @ W_jᵀ,   W_j = Σ_k where(idx_k == j, val_k, 0)   (c, g)
 
-Validated in interpret mode against ref.nm_matmul_ref over shape/dtype
-sweeps (tests/test_kernels.py).
+where W_j is column j of every group: the same per-slot masked-select
+expansion as ``kernels/ref.nm_expand``, contracted plane by plane.
+
+Grid: (x_tiles, c_tiles, g_tiles) — g is the contraction dim, accumulated
+in a fp32 VMEM scratch; the output tile is written once on the last g
+step (standard Pallas accumulation pattern).  Every block is (8, 128)-
+aligned or spans its whole dimension, and kernels/ops.choose_tiles keeps
+the tile set inside the VMEM budget.  Compiles for TPU v5e at every
+projection shape of h2o-danube-1.8b (tests/test_tpu_compile.py) and is
+validated in interpret mode against ref.nm_matmul_ref
+(tests/test_kernels.py).
 """
 from __future__ import annotations
 
@@ -40,48 +44,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.sparsity import nm_storage_shapes
+
 Array = jax.Array
+
+
+def _slot_index(idx_ref, k: int, idx_bits: int) -> Array:
+    """In-group positions of kept slot k for the current tile, int32."""
+    if idx_bits == 4:
+        raw = idx_ref[k // 2].astype(jnp.int32)        # sign-extends
+        return (raw >> (4 * (k % 2))) & 0xF
+    return idx_ref[k].astype(jnp.int32)
 
 
 def _nm_kernel(x_ref, val_ref, idx_ref, o_ref, acc_ref, *, m: int, keep: int,
                nsteps: int, idx_bits: int):
-    """One (B_tile × c_tile) output tile; contraction step j over b tiles."""
-    j = pl.program_id(2)
+    """One (B_tile × c_tile) output tile; contraction step j over g tiles."""
+    step = pl.program_id(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    vals = val_ref[...]                                   # (ct, gt·keep)
-    ct = vals.shape[0]
-    gt = vals.shape[1] // keep
+    idx = [_slot_index(idx_ref, k, idx_bits) for k in range(keep)]
+    acc = acc_ref[...]
+    for j in range(m):
+        # dense plane j (ct, gt): the kept value whose position is j, else 0
+        w_j = jnp.zeros(idx[0].shape, jnp.float32)
+        for k in range(keep):
+            w_j = jnp.where(idx[k] == j, val_ref[k].astype(jnp.float32), w_j)
+        x_j = x_ref[j]                                  # (Bt, gt)
+        acc += jax.lax.dot_general(
+            x_j, w_j.astype(x_j.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    acc_ref[...] = acc
 
-    if idx_bits == 4:
-        raw = idx_ref[...].astype(jnp.int32)              # sign-extended
-        lo = raw & 0xF
-        hi = (raw >> 4) & 0xF
-        idx = jnp.stack([lo, hi], axis=-1).reshape(ct, -1)[:, :gt * keep]
-    else:
-        idx = idx_ref[...].astype(jnp.int32)
-
-    # expand compressed tile → dense (ct, gt·m) in VMEM: in-group scatter as
-    # a static loop of per-slot selects (no (ct, gt, keep, m) one-hot)
-    vals3 = vals.reshape(ct, gt, keep).astype(jnp.float32)
-    idx3 = idx.reshape(ct, gt, keep)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (ct, gt, m), 2)
-    dense = jnp.zeros((ct, gt, m), jnp.float32)
-    for k in range(keep):
-        dense = dense + jnp.where(idx3[:, :, k][..., None] == iota,
-                                  vals3[:, :, k][..., None], 0.0)
-    dense = dense.reshape(ct, gt * m)                     # (ct, bt)
-
-    x = x_ref[...].astype(jnp.float32)                    # (Bt, bt)
-    acc_ref[...] += jax.lax.dot_general(
-        x, dense, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(j == nsteps - 1)
+    @pl.when(step == nsteps - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -93,55 +92,54 @@ def _nm_kernel(x_ref, val_ref, idx_ref, o_ref, acc_ref, *, m: int, keep: int,
 )
 def nm_matmul(
     x: Array,          # (B, b) activations
-    values: Array,     # (c, g·keep)
-    indices: Array,    # (c, g·keep) int8, or (c, ⌈g·keep/2⌉) when idx_bits=4
+    values: Array,     # (keep, c, g)
+    indices: Array,    # (keep, c, g) int8, or (⌈keep/2⌉, c, g) when idx_bits=4
     *,
     n: int,
     m: int,
     b: int,
     idx_bits: int = 8,
-    block_b: int = 512,
-    block_c: int = 256,
+    block_b: int = 0,
+    block_c: int = 0,
     block_x: int = 0,
     interpret: bool = False,
 ) -> Array:
-    """y = x @ Wᵀ with W the n:m compressed (c, b) weight matrix."""
+    """y = x @ Wᵀ with W the n:m compressed (c, b) weight matrix.
+
+    Tile sizes of 0 take the whole dimension; ``block_b`` counts dense
+    columns (the kernel's contraction tile is ``block_b // m`` groups).
+    """
     B = x.shape[0]
-    c = values.shape[0]
     keep = m - n
-    gk = (b // m) * keep
-    assert b % m == 0 and values.shape[1] == gk, \
+    g = b // m
+    c = values.shape[1]
+    vshape, ishape = nm_storage_shapes(c, b, n, m, idx_bits)
+    assert b % m == 0 and values.shape == vshape, \
         f"bad compressed layout: {values.shape} for b={b} {n}:{m}"
-    assert indices.shape[1] == ((gk + 1) // 2 if idx_bits == 4 else gk), \
+    assert indices.shape == ishape, \
         f"bad index layout: {indices.shape} for idx_bits={idx_bits}"
+    planes = ishape[0]
 
-    bb = min(block_b, b)
-    bc = min(block_c, c)
-    bx = B if block_x == 0 else min(block_x, B)
-    assert b % bb == 0 and c % bc == 0 and B % bx == 0
-    assert bb % m == 0
-    gb = (bb // m) * keep        # compressed width of one b tile
-    nsteps = b // bb
-    if idx_bits == 4:
-        assert nsteps == 1 or gb % 2 == 0, \
-            f"4-bit index tiling needs an even per-tile width, got {gb}"
-        gi = (gb + 1) // 2       # byte width of one index tile
-    else:
-        gi = gb
+    bg = block_b // m if block_b else g
+    bc = block_c or c
+    bx = block_x or B
+    assert g % bg == 0 and c % bc == 0 and B % bx == 0, (bg, bc, bx)
+    nsteps = g // bg
 
-    grid = (B // bx, c // bc, nsteps)
+    # the m strided activation planes x_j = x[:, j::m], lane-dense (m, B, g)
+    xs = x.reshape(B, g, m).transpose(2, 0, 1)
     kernel = functools.partial(_nm_kernel, m=m, keep=keep, nsteps=nsteps,
                                idx_bits=idx_bits)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B // bx, c // bc, nsteps),
         in_specs=[
-            pl.BlockSpec((bx, bb), lambda i, k, j: (i, j)),
-            pl.BlockSpec((bc, gb), lambda i, k, j: (k, j)),
-            pl.BlockSpec((bc, gi), lambda i, k, j: (k, j)),
+            pl.BlockSpec((m, bx, bg), lambda i, k, j: (0, i, j)),
+            pl.BlockSpec((keep, bc, bg), lambda i, k, j: (0, k, j)),
+            pl.BlockSpec((planes, bc, bg), lambda i, k, j: (0, k, j)),
         ],
         out_specs=pl.BlockSpec((bx, bc), lambda i, k, j: (i, k)),
         out_shape=jax.ShapeDtypeStruct((B, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((bx, bc), jnp.float32)],
         interpret=interpret,
-    )(x, values, indices)
+    )(xs, values, indices)

@@ -20,7 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.sparsity import NmCompressed, NmStackedCompressed
+from repro.core.sparsity import (NmCompressed, NmStackedCompressed,
+                                 nm_storage_shapes)
 from repro.kernels import nm_spmm, hessian_accum, ref
 
 Array = jax.Array
@@ -59,25 +60,61 @@ def _round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
-def choose_tiles(B: int, c: int, b: int, m: int, keep: int,
-                 idx_bits: int = 4) -> dict:
-    """Shape-keyed Pallas tile sizes for an (B, b) × (c, b)ᵀ n:m matmul.
+# Scoped-VMEM budget for one n:m kernel instance.  Mosaic's default scoped
+# limit is 16 MiB on v5e; the margin covers what the estimate below leaves
+# out (semaphores, internal scratch).
+VMEM_BUDGET = 12 * 2**20
 
-    block_b must divide b (the compressed layout fixes b — we never pad the
-    contraction dim) and, for nibble-packed indices with >1 contraction
-    step, keep index tiles byte-aligned.  block_c/block_x only bound the
-    padding the wrapper applies, so they just round small dims up to the
-    sublane multiple.
+
+def nm_vmem_bytes(bx: int, bc: int, bg: int, m: int, keep: int,
+                  idx_bits: int, x_bytes: int, w_bytes: int) -> int:
+    """Scoped VMEM of one kernel instance with (bx, bc, bg) tiles.
+
+    Double-buffered input/output blocks plus the in-kernel fp32/int32
+    temporaries per (c, g) tile element: one decoded index plane per kept
+    slot and the dense plane under construction (fp32 and MXU dtype).
     """
-    bb = b
-    for cand in (512, 256, 128):
-        if cand < b and b % cand == 0 and cand % m == 0 and \
-                (idx_bits == 8 or ((cand // m) * keep) % 2 == 0):
-            bb = cand
-            break
-    bc = min(256, _round_up(c, 8))
-    bx = min(128, _round_up(max(B, 1), 8))
-    return {"block_b": bb, "block_c": bc, "block_x": bx}
+    _, (planes, _, _) = nm_storage_shapes(bc, bg * m, m - keep, m, idx_bits)
+    weight = bc * bg * (2 * (keep * w_bytes + planes) + 4 * (keep + 3))
+    acts = 2 * m * bx * bg * x_bytes
+    out = bx * bc * (4 + 2 * x_bytes)
+    return weight + acts + out
+
+
+def choose_tiles(B: int, c: int, b: int, m: int, keep: int,
+                 idx_bits: int = 4, x_bytes: int = 4,
+                 w_bytes: int = 4) -> dict:
+    """Pallas tile sizes for an (B, b) × (c, b)ᵀ n:m matmul.
+
+    Every block the kernel declares is (8, 128)-aligned or spans its whole
+    dimension, and the set fits ``VMEM_BUDGET``:
+
+    * contraction (g = b/m groups on lanes): the whole of g when it fits,
+      else the largest 128-multiple divisor — the compressed layout fixes
+      b, so it is never padded (at b = 6912, g = 1728 has no such divisor
+      and is taken whole);
+    * rows c (output lanes): the largest 128-multiple divisor of c that
+      fits, or c whole when c is below 1024; other c are zero-padded by the
+      wrapper to 128-row tiles;
+    * batch B (sublanes): whole up to 128 rows, else 128-row tiles (padded);
+      halved in multiples of 8 only if nothing else fits.
+
+    Tiles are preferred in that order of size: a larger batch tile re-reads
+    the weights fewer times, which is what decode is bound by.
+    """
+    g = b // m
+    bx0 = B if B <= 128 else 128
+    bxs = [bx0] + [v for v in (64, 32, 16, 8) if v < bx0]
+    bgs = [g] + [v for v in (1024, 512, 256, 128) if v < g and g % v == 0]
+    bcs = sorted({v for v in range(128, min(c, 2048) + 1, 128) if c % v == 0}
+                 | ({c} if c < 1024 else set()), reverse=True) or [128]
+    for bx in bxs:
+        for bg in bgs:
+            for bc in bcs:
+                if nm_vmem_bytes(bx, bc, bg, m, keep, idx_bits, x_bytes,
+                                 w_bytes) <= VMEM_BUDGET:
+                    return {"block_b": bg * m, "block_c": bc, "block_x": bx}
+    return {"block_b": bgs[-1] * m, "block_c": bcs[-1], "block_x": bxs[-1]}
 
 
 def nm_matmul(x: Array, packed: NmCompressed, *, impl: str = "",
@@ -85,9 +122,9 @@ def nm_matmul(x: Array, packed: NmCompressed, *, impl: str = "",
               block_c: int = 0, block_x: int = 0) -> Array:
     """y = x @ Wᵀ for n:m compressed W (c, b); x (..., b) → y (..., c).
 
-    Non-tile-divisible shapes (odd c, B not a multiple of the x tile) are
-    zero-padded for the Pallas path and sliced back — zero rows cost nothing
-    and zero activations contribute nothing.
+    Shapes the tiles do not divide (c off the 128 grid, B beyond one
+    batch tile) are zero-padded for the Pallas path and sliced back — zero
+    rows cost nothing and zero activations contribute nothing.
     """
     cfg = cfg if cfg is not None else NmKernelConfig()
     use = _resolve_impl(impl or cfg.impl)
@@ -101,9 +138,10 @@ def nm_matmul(x: Array, packed: NmCompressed, *, impl: str = "",
         return y.reshape(*lead, -1)
 
     keep = packed.kept_per_group
-    c = packed.values.shape[0]
+    c = packed.values.shape[1]
     B = x2.shape[0]
-    tiles = choose_tiles(B, c, packed.b, packed.m, keep, packed.idx_bits)
+    tiles = choose_tiles(B, c, packed.b, packed.m, keep, packed.idx_bits,
+                         x2.dtype.itemsize, packed.values.dtype.itemsize)
     for name, override in (("block_b", block_b or cfg.block_b),
                            ("block_c", block_c or cfg.block_c),
                            ("block_x", block_x or cfg.block_x)):
@@ -114,8 +152,8 @@ def nm_matmul(x: Array, packed: NmCompressed, *, impl: str = "",
     b_pad = _round_up(B, tiles["block_x"]) - B
     values, indices = packed.values, packed.indices
     if c_pad:
-        values = jnp.pad(values, ((0, c_pad), (0, 0)))
-        indices = jnp.pad(indices, ((0, c_pad), (0, 0)))
+        values = jnp.pad(values, ((0, 0), (0, c_pad), (0, 0)))
+        indices = jnp.pad(indices, ((0, 0), (0, c_pad), (0, 0)))
     if b_pad:
         x2 = jnp.pad(x2, ((0, b_pad), (0, 0)))
 

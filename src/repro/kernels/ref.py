@@ -11,32 +11,32 @@ Array = jax.Array
 
 def nm_expand(values: Array, indices: Array, n: int, m: int, b: int,
               idx_bits: int = 8) -> Array:
-    """Dense (c, b) from group-major n:m storage — in-group scatter.
+    """Dense (c, b) from slot-major n:m storage — in-group scatter.
 
-    values: (c, g·keep) with g = b/m groups of ``keep = m − n`` kept weights
-    each; indices are int8 in-group positions (0..m−1), one per byte
-    (idx_bits=8) or two per byte, low nibble first (idx_bits=4).
+    values: (keep, c, g) with g = b/m groups and ``keep = m − n`` kept
+    weights each (plane k = slot k of every group); indices are the
+    matching int8 in-group positions (0..m−1), one per byte (idx_bits=8,
+    (keep, c, g)) or two slots per byte, low nibble first (idx_bits=4,
+    (⌈keep/2⌉, c, g)).
 
-    Each kept value is placed at its in-group position by a static loop of
-    ``keep`` masked selects — the same formulation the Pallas kernel runs
-    per VMEM tile, and the fastest CPU variant measured (an XLA scatter
-    serializes; the old one-hot formulation materialized a (c, g, keep, m)
-    fp32 tensor and burned m/keep× extra FLOPs for the same placement).
-    Placement only, no arithmetic: the expansion is bit-exact in the stored
-    dtype.
+    Dense column j of every group is ``Σ_k where(idx_k == j, val_k, 0)`` —
+    the same per-slot masked selects the Pallas kernel runs per VMEM tile
+    to build its m dense planes, and the fastest CPU variant measured (an
+    XLA scatter serializes; the old one-hot formulation materialized a
+    (c, g, keep, m) fp32 tensor and burned m/keep× extra FLOPs for the same
+    placement).  Placement only, no arithmetic: the expansion is bit-exact
+    in the stored dtype.
     """
     keep = m - n
-    c = values.shape[0]
-    g = b // m
     if idx_bits == 4:
-        indices = unpack_indices4(indices, g * keep)
-    vals = values.reshape(c, g, keep)
-    idx = indices.reshape(c, g, keep).astype(jnp.int32)
-    iota = jnp.arange(m)[None, None, :]
-    dense = jnp.zeros((c, g, m), values.dtype)
+        indices = unpack_indices4(indices, keep)
+    idx = indices.astype(jnp.int32)[..., None]               # (keep, c, g, 1)
+    vals = values[..., None]
+    iota = jnp.arange(m)
+    c = values.shape[1]
+    dense = jnp.zeros((c, b // m, m), values.dtype)
     for k in range(keep):
-        dense = dense + jnp.where(idx[:, :, k][..., None] == iota,
-                                  vals[:, :, k][..., None], 0)
+        dense = dense + jnp.where(idx[k] == iota, vals[k], 0)
     return dense.reshape(c, b)
 
 
@@ -55,7 +55,7 @@ def nm_matmul_ref(x: Array, values: Array, indices: Array, n: int, m: int,
 
 def nm_expand_stacked(values: Array, indices: Array, n: int, m: int, b: int,
                       idx_bits: int = 8) -> Array:
-    """Dense (E, c, b) from stacked group-major n:m storage.
+    """Dense (E, c, b) from stacked slot-major n:m storage.
 
     The masked-select keep-loop of :func:`nm_expand` vmapped over the
     leading expert axis — placement only, bit-exact in the stored dtype,

@@ -36,6 +36,7 @@ from repro.core import (
 from repro.data.pipeline import calibration_batches, heldout_loss
 from repro.faults import FaultPlan
 from repro.models.model_builder import build_model, ModelAdapter
+from repro.util.compile_cache import enable_compile_cache
 
 # transformer-family shorthand globs ('*' crosses '/'); moe covers both the
 # stacked expert slices and the shared FFN
@@ -173,6 +174,7 @@ def main():
         ap.error("--resume requires --job-dir")
     faults = FaultPlan.load(args.fault_plan) if args.fault_plan else None
     plan = build_plan(args)
+    enable_compile_cache()
     prune_arch(args.arch, plan, reduced=not args.full,
                report_path=args.report, job_dir=args.job_dir,
                resume=args.resume, on_singular=args.on_singular,

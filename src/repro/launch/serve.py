@@ -4,6 +4,12 @@ checkpoint.
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --requests 8 --prompt-len 16 --max-new 12 --nm
 
+``--full`` builds the config at its published widths instead of the toy
+``REDUCED`` one (it needs an accelerator), e.g. on one TPU v5e:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch h2o-danube-1.8b \
+        --full --nm
+
 ``--nm`` prunes 2:4 with Thanos first and serves from the NmCompressed
 representation (paper §4.8; HBM-traffic win quantified in
 benchmarks/nm_decode_roofline.py).  ``--plan recipe.json`` prunes with a
@@ -42,6 +48,7 @@ from repro.core import PruneConfig, PrunePlan
 from repro.models.model_builder import build_model
 from repro.serve import Request, ServeConfig, ServingEngine
 from repro.serve.compressed import compress_params, compressed_bytes
+from repro.util.compile_cache import enable_compile_cache
 
 
 def main():
@@ -95,6 +102,8 @@ def main():
     ap.add_argument("--max-queued", type=int, default=0,
                     help="bound the request queue; past it submissions are "
                          "rejected (HTTP: 503 + Retry-After)")
+    ap.add_argument("--full", action="store_true",
+                    help="full config (needs real accelerators)")
     ap.add_argument("--drain-timeout", type=float, default=5.0,
                     help="seconds to finish in-flight requests at HTTP "
                          "shutdown (drain mode)")
@@ -102,16 +111,20 @@ def main():
     args.supervise = (args.supervise or bool(args.fault_plan)
                       or args.snapshot_every > 0 or args.retry_budget > 0)
 
-    cfg = registry.get_config(args.arch, reduced=True)
+    enable_compile_cache()
+    cfg = registry.get_config(args.arch, reduced=not args.full)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
 
+    # prune_arch inits the same seed-0 params itself; the dense init below
+    # is only built when nothing is pruned, so a full-width run never
+    # holds two copies of the weights
     if args.plan:
         from repro.launch.prune import prune_arch
 
         plan = PrunePlan.load(args.plan)
         print(f"pruning with recipe {args.plan} ({len(plan.rules)} rules)…")
-        pruned, report, _ = prune_arch(args.arch, plan, log=None)
+        pruned, report, _ = prune_arch(args.arch, plan,
+                                       reduced=not args.full, log=None)
         params = compress_params(pruned, report.masks, plan=report.plan)
         comp, dense = compressed_bytes(params)
         if dense:
@@ -128,12 +141,14 @@ def main():
         pruned, report, _ = prune_arch(
             args.arch, PruneConfig(method="thanos", pattern="nm", n=2, m=4,
                                    block_size=64),
-            log=None,
+            reduced=not args.full, log=None,
         )
         params = compress_params(pruned, report.masks, 2, 4)
         comp, dense = compressed_bytes(params)
         if dense:
             print(f"compressed weight bytes: {comp / dense:.3f} of dense")
+    else:
+        params = model.init(jax.random.PRNGKey(0))
 
     max_len = args.prompt_len + args.max_new + 8
     if args.paged and max_len % args.page_size:

@@ -333,7 +333,7 @@ def abstract_nm_params(model, n: int | None = None, m: int | None = None,
     """Abstract params with prunable linears swapped for compressed
     ShapeDtypeStruct pairs — 2-D kernels lower to ``NmCompressed`` and
     3-D MoE expert stacks to one ``NmStackedCompressed`` leaf (values
-    (E, d_out, g·keep) + nibble-packed indices), mirroring what
+    (E, keep, d_out, g) + nibble-packed indices), mirroring what
     ``serve.compressed.compress_params`` produces.
 
     With a global ``(n, m)`` every eligible linear compresses; with a
@@ -346,7 +346,7 @@ def abstract_nm_params(model, n: int | None = None, m: int | None = None,
     dense in the abstract tree).
     """
     from repro.core.sparsity import (NON_STREAMABLE_KERNELS, NmCompressed,
-                                     NmStackedCompressed)
+                                     NmStackedCompressed, nm_storage_shapes)
 
     if plan is None and (n is None or m is None):
         raise ValueError("abstract_nm_params needs (n, m) or plan=")
@@ -382,11 +382,10 @@ def abstract_nm_params(model, n: int | None = None, m: int | None = None,
         d_in, d_out = kernel.shape
         if d_in % pm:
             continue
-        keep = pm - pn
-        gk = d_in // pm * keep
+        vshape, ishape = nm_storage_shapes(d_out, d_in, pn, pm)
         packed = NmCompressed(
-            values=jax.ShapeDtypeStruct((d_out, gk), kernel.dtype),
-            indices=jax.ShapeDtypeStruct((d_out, (gk + 1) // 2), jnp.int8),
+            values=jax.ShapeDtypeStruct(vshape, kernel.dtype),
+            indices=jax.ShapeDtypeStruct(ishape, jnp.int8),
             n=pn, m=pm, b=d_in, idx_bits=4,
         )
         a = set_path(a, path[:-1] + ("w",), packed)
@@ -402,10 +401,10 @@ def abstract_nm_params(model, n: int | None = None, m: int | None = None,
         pn, pm = next(iter(got.values()))
         if d_in % pm:
             continue
-        gk = d_in // pm * (pm - pn)
+        vshape, ishape = nm_storage_shapes(d_out, d_in, pn, pm)
         packed = NmStackedCompressed(
-            values=jax.ShapeDtypeStruct((E, d_out, gk), kernel.dtype),
-            indices=jax.ShapeDtypeStruct((E, d_out, (gk + 1) // 2), jnp.int8),
+            values=jax.ShapeDtypeStruct((E, *vshape), kernel.dtype),
+            indices=jax.ShapeDtypeStruct((E, *ishape), jnp.int8),
             n=pn, m=pm, b=d_in, E=E, idx_bits=4,
         )
         a = set_path(a, base, packed)

@@ -179,7 +179,8 @@ def _prefill_fn(model, params, cache, tokens, start):
         cache, _ = carry
         tok = jax.lax.dynamic_slice(tokens, (0, i), (tokens.shape[0], 1))
         logits, cache = model.decode_step(params, cache, tok, i)
-        return cache, logits[:, -1, :]
+        # fp32 carry whatever the model dtype: exact for bf16 logits
+        return cache, logits[:, -1, :].astype(jnp.float32)
 
     B = tokens.shape[0]
     init_logits = jnp.zeros((B, model.cfg.vocab_size), jnp.float32)

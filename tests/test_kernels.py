@@ -36,9 +36,9 @@ class TestPackUnpack:
     @pytest.mark.parametrize("c,L", [(3, 8), (5, 7), (1, 1), (4, 13)])
     def test_indices4_roundtrip(self, c, L):
         rng = np.random.default_rng(c * 31 + L)
-        idx = jnp.asarray(rng.integers(0, 16, size=(c, L)), jnp.int8)
+        idx = jnp.asarray(rng.integers(0, 16, size=(L, c, 5)), jnp.int8)
         packed = pack_indices4(idx)
-        assert packed.shape == (c, (L + 1) // 2)
+        assert packed.shape == ((L + 1) // 2, c, 5)
         np.testing.assert_array_equal(
             np.asarray(unpack_indices4(packed, L)), np.asarray(idx))
 
@@ -123,16 +123,26 @@ class TestNmSpmm:
         assert y.shape == (2, 3, 32)
 
     def test_choose_tiles_respects_layout(self):
-        """Chosen b tiles divide b, align to m, and keep 4-bit index tiles
-        on byte boundaries whenever more than one contraction step runs."""
-        for (B, c, b, m, keep, bits) in [
-            (8, 2048, 2048, 4, 2, 4), (3, 37, 96, 8, 3, 4),
-            (1, 7, 520, 4, 3, 4), (16, 512, 1024, 8, 4, 8),
+        """Chosen tiles divide the contraction (never padded), every block
+        is (8, 128)-aligned or spans its whole dimension, and the tile set
+        fits the kernel's VMEM budget."""
+        for (B, c, b, m, keep, bits, nbytes) in [
+            (8, 2048, 2048, 4, 2, 4, 4), (3, 37, 96, 8, 3, 4, 4),
+            (1, 7, 520, 4, 3, 4, 4), (16, 512, 1024, 8, 4, 8, 4),
+            (4, 2560, 6912, 4, 2, 4, 2), (128, 6912, 2560, 4, 2, 4, 2),
+            (300, 1000, 4096, 4, 2, 4, 2),
         ]:
-            t = ops.choose_tiles(B, c, b, m, keep, bits)
-            assert b % t["block_b"] == 0 and t["block_b"] % m == 0
-            gb = t["block_b"] // m * keep
-            assert bits == 8 or t["block_b"] == b or gb % 2 == 0
+            t = ops.choose_tiles(B, c, b, m, keep, bits, nbytes, nbytes)
+            g, bg = b // m, t["block_b"] // m
+            assert t["block_b"] % m == 0 and g % bg == 0
+            assert bg == g or bg % 128 == 0
+            assert t["block_c"] == c or t["block_c"] % 128 == 0
+            assert t["block_x"] == B or t["block_x"] % 8 == 0
+            assert ops.nm_vmem_bytes(t["block_x"], t["block_c"], bg, m, keep,
+                                     bits, nbytes, nbytes) <= ops.VMEM_BUDGET
+        # b = 6912: g = 1728 has no 128-multiple divisor — taken whole
+        assert ops.choose_tiles(4, 2560, 6912, 4, 2, 4, 2, 2)["block_b"] == \
+            6912
 
 
 class TestHessianAccum:

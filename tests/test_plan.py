@@ -419,7 +419,7 @@ def test_compress_params_packs_expert_slices():
         leaf = comp["moe"]["gate"]["w"]
         assert isinstance(leaf, NmStackedCompressed)
         assert (leaf.E, leaf.n, leaf.m, leaf.b) == (E, 2, 4, d_in)
-        assert leaf.values.shape == (E, d_out, d_in // 4 * 2)
+        assert leaf.values.shape == (E, 2, d_out, d_in // 4)
 
 
 def test_registry_view_eq_is_total():

@@ -80,12 +80,13 @@ def test_pack_unpack_roundtrip(c, groups, nm, idx_bits, seed):
        seed=st.integers(0, 10_000))
 @settings(**SETTINGS)
 def test_indices4_roundtrip_any_length(c, length, seed):
-    """Two-per-byte nibble packing round-trips for any (c, L), odd L
-    included (final high nibble is padding)."""
+    """Two-slots-per-byte nibble packing round-trips for any number of
+    slot planes L over (c, 3) planes, odd L included (final high nibble is
+    padding)."""
     rng = np.random.default_rng(seed)
-    idx = jnp.asarray(rng.integers(0, 16, size=(c, length)), jnp.int8)
+    idx = jnp.asarray(rng.integers(0, 16, size=(length, c, 3)), jnp.int8)
     packed = pack_indices4(idx)
-    assert packed.shape == (c, (length + 1) // 2)
+    assert packed.shape == ((length + 1) // 2, c, 3)
     assert np.array_equal(np.asarray(unpack_indices4(packed, length)),
                           np.asarray(idx))
 

@@ -60,10 +60,10 @@ def test_pack_unpack_roundtrip(E, n, m, idx_bits):
     sparse = w * (1 - mask)
     packed = pack_nm_stacked(sparse, mask, n, m, idx_bits=idx_bits)
     assert (packed.E, packed.b) == (E, b)
-    assert packed.values.shape == (E, c, (b // m) * (m - n))
-    gk = (b // m) * (m - n)
+    keep, g = m - n, b // m
+    assert packed.values.shape == (E, keep, c, g)
     assert packed.indices.shape == \
-        (E, c, gk if idx_bits == 8 else (gk + 1) // 2)
+        (E, keep if idx_bits == 8 else (keep + 1) // 2, c, g)
     np.testing.assert_array_equal(np.asarray(unpack_nm_stacked(packed)),
                                   np.asarray(sparse))
 
@@ -401,9 +401,8 @@ def test_abstract_nm_params_lowers_expert_stacks():
     leaf = get_path(a, ("blocks", 0, "moe", "gate", "w"))
     assert isinstance(leaf, NmStackedCompressed)
     E, f, d = cfg.num_experts, cfg.moe_d_ff, cfg.d_model
-    gk = d // 4 * 2
-    assert leaf.values.shape == (E, f, gk)
-    assert leaf.indices.shape == (E, f, (gk + 1) // 2)
+    assert leaf.values.shape == (E, 2, f, d // 4)
+    assert leaf.indices.shape == (E, 1, f, d // 4)
     assert (leaf.n, leaf.m, leaf.b, leaf.E) == (2, 4, d, E)
     # attn is unstructured under the recipe → dense SDS
     attn = get_path(a, ("blocks", 0, "attn", "wq", "w"))
