@@ -63,6 +63,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.configs import registry  # noqa: E402
 from repro.core import (  # noqa: E402
     HessianAccumulator, PruneConfig, PrunePlan, PruneRule, get_path,
@@ -541,31 +542,24 @@ def four_chip_phase(reduced: bool, n_devices: int = 4, log=None):
 # main
 # --------------------------------------------------------------------------
 class PhaseMeter:
-    """Per-phase wall time, backend compiles, persistent-cache hits and the
-    device's peak memory so far, printed as one line per phase."""
+    """Per-phase wall time, backend compiles, persistent-cache hits
+    (``repro.obs``) and the device's peak memory so far, printed as one
+    line per phase."""
 
     def __init__(self):
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+        obs.install()
 
     @contextlib.contextmanager
     def phase(self, name: str, result: dict):
-        c0, h0, t0 = self.compiles, self.cache_hits, time.perf_counter()
+        j0, t0 = obs.jit_counts(), time.perf_counter()
         yield
+        j1 = obs.jit_counts()
+        hits = j1["persistent_cache_hits"] - j0["persistent_cache_hits"]
         stats = jax.devices()[0].memory_stats() or {}
         line = {"wall_s": round(time.perf_counter() - t0, 3),
-                "compiles": self.compiles - c0 - (self.cache_hits - h0),
-                "cache_hits": self.cache_hits - h0,
+                "compiles": (j1["backend_compiles"] - j0["backend_compiles"]
+                             - hits),
+                "cache_hits": hits,
                 "peak_bytes": stats.get("peak_bytes_in_use"), **result}
         print(f"[smoke run, not a benchmark] {name}: {json.dumps(line)}",
               flush=True)

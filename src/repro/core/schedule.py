@@ -24,7 +24,9 @@ from typing import Any, Callable, Iterable, Mapping, Protocol
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from repro import obs
 from repro.core.api import (PruneConfig, method_spec, prune_layer,  # noqa: F401
                             prune_layer_guarded)
 from repro.core.hessian import HessianAccumulator
@@ -195,6 +197,43 @@ class PruneReport:
         atomic_write_text(path, self.to_json() + "\n")
 
 
+def _accumulate(accs: dict, caps: dict, plan: PrunePlan | None = None,
+                faults=None) -> None:
+    """Add one calibration batch's captured inputs ``caps`` to the
+    per-layer Hessian accumulators ``accs``, opening one per new layer;
+    layers the ``plan`` leaves dense are not accumulated."""
+    for path, x in caps.items():
+        if plan is not None and path not in accs and \
+                plan.cfg_for(path) is None:
+            continue                     # skip rule: layer stays dense
+        # MoE expert slices tape (activations, row-validity) pairs: only
+        # routed capacity rows count as calibration samples
+        valid = None
+        if isinstance(x, tuple):
+            x, valid = x
+        if path not in accs:
+            accs[path] = HessianAccumulator.init(x.shape[-1])
+        if faults is not None and faults.fire("hessian_accum") is not None:
+            # poisoned activations: the accumulator's non-finite guard
+            # must swallow the batch, not the Hessian
+            x = jnp.full_like(x, jnp.nan)
+        accs[path] = accs[path].update(x, valid)
+
+
+def _block_jits(adapter: BlockwiseAdapter):
+    """Block ``i``'s forward, and its forward capturing every linear's
+    inputs, jitted; a trace shows them as ``jit_prune_forward`` and
+    ``jit_prune_capture``."""
+    def prune_forward(p, c, i):
+        return adapter.block_apply(p, i, c, capture=False)[0]
+
+    def prune_capture(p, c, i):
+        return adapter.block_apply(p, i, c, capture=True)
+
+    return (jax.jit(prune_forward, static_argnums=(2,)),
+            jax.jit(prune_capture, static_argnums=(2,)))
+
+
 def prune_model(
     params,
     adapter: BlockwiseAdapter,
@@ -235,163 +274,150 @@ def prune_model(
     * ``min_calib_samples`` — a data-aware layer whose accumulator closed
       with fewer calibration tokens raises ``InsufficientCalibration``.
     """
-    plan = as_plan(plan)
-    t_start = time.perf_counter()
-    batches = list(batches)
-    if plan.allocation is not None:
-        # a recipe carrying an allocation block expands itself here: one
-        # extra dense calibration pass collects the per-layer Hessian-trace
-        # stats, and the *expanded* plan (allocation=None) is what the
-        # report embeds — replaying the artifact reproduces this run
-        # without re-running the allocation.
-        plan = plan.allocate_sparsity(
-            collect_hessian_stats(params, adapter, batches))
-    carries = [adapter.prepare(params, b) for b in batches]
+    obs.install()
+    with TraceAnnotation("prune.call"):
+        plan = as_plan(plan)
+        t_start = time.perf_counter()
+        batches = list(batches)
+        if plan.allocation is not None:
+            # a recipe carrying an allocation block expands itself here:
+            # one extra dense calibration pass collects the per-layer
+            # Hessian-trace stats, and the *expanded* plan (allocation=None)
+            # is what the report embeds — replaying the artifact reproduces
+            # this run without re-running the allocation.
+            plan = plan.allocate_sparsity(
+                collect_hessian_stats(params, adapter, batches))
+        carries = [adapter.prepare(params, b) for b in batches]
 
-    solver = None
-    if mesh is not None:
-        from repro.dist.prune import prune_layer_sharded
+        solver = None
+        if mesh is not None:
+            from repro.dist.prune import prune_layer_sharded
 
-        def solver(w, h, cfg):  # noqa: F811 — row-parallel per-layer solve
-            return prune_layer_sharded(w, h, cfg, mesh)
+            def solver(w, h, cfg):  # noqa: F811 — row-parallel layer solve
+                return prune_layer_sharded(w, h, cfg, mesh)
 
-    block_fwd = jax.jit(
-        lambda p, c, i: adapter.block_apply(p, i, c, capture=False)[0],
-        static_argnums=(2,),
-    )
-    block_cap = jax.jit(
-        lambda p, c, i: adapter.block_apply(p, i, c, capture=True),
-        static_argnums=(2,),
-    )
+        block_fwd, block_cap = _block_jits(adapter)
 
-    reports: list[LayerReport] = []
-    masks: dict[Path, Array] = {}
-    # Hessian accumulators persist ACROSS blocks: weight-shared layers
-    # (e.g. Zamba2's interleaved shared attention) are invoked at several
-    # block indices and pruned once, at their last site, with statistics
-    # accumulated over every invocation — the correct treatment of weight
-    # sharing under objective Eq. 1.  Entries are dropped once consumed.
-    accs: dict[Path, HessianAccumulator] = {}
-    ordinal = 0                  # global sequential layer index (journal key)
+        reports: list[LayerReport] = []
+        masks: dict[Path, Array] = {}
+        # Hessian accumulators persist ACROSS blocks: weight-shared layers
+        # (e.g. Zamba2's interleaved shared attention) are invoked at
+        # several block indices and pruned once, at their last site, with
+        # statistics accumulated over every invocation — the correct
+        # treatment of weight sharing under objective Eq. 1.  Entries are
+        # dropped once consumed.
+        accs: dict[Path, HessianAccumulator] = {}
+        ordinal = 0              # global sequential layer index (journal key)
 
-    for i in range(adapter.num_blocks(params)):
-        # ---- pass 1: capture inputs, accumulate Hessians -----------------
-        # Runs on resume too: journaled blocks replay their (deterministic)
-        # forwards so cross-block accumulators — weight-shared layers —
-        # and next-block carries are bitwise those of the original run.
-        for bi, carry in enumerate(carries):
-            if faults is not None and \
-                    faults.fire("calib_batch", uid=i) is not None:
-                raise CalibrationError(
-                    f"injected calibration failure (block {i}, batch {bi})",
-                    site="calib_batch")
-            _, caps = block_cap(params, carry, i)
-            for path, x in caps.items():
-                if path not in accs and plan.cfg_for(path) is None:
-                    continue                 # skip rule: layer stays dense
-                # MoE expert slices tape (activations, row-validity) pairs:
-                # only routed capacity rows count as calibration samples
-                valid = None
-                if isinstance(x, tuple):
-                    x, valid = x
-                if path not in accs:
-                    accs[path] = HessianAccumulator.init(x.shape[-1])
-                if faults is not None and \
-                        faults.fire("hessian_accum") is not None:
-                    # poisoned activations: the accumulator's non-finite
-                    # guard must swallow the batch, not the Hessian
-                    x = jnp.full_like(x, jnp.nan)
-                accs[path] = accs[path].update(x, valid)
+        for i in range(adapter.num_blocks(params)):
+            with TraceAnnotation("prune.block", block=i):
+                # ---- pass 1: capture inputs, accumulate Hessians ---------
+                # Runs on resume too: journaled blocks replay their
+                # (deterministic) forwards so cross-block accumulators —
+                # weight-shared layers — and next-block carries are bitwise
+                # those of the original run.
+                with TraceAnnotation("prune.capture", block=i):
+                    for bi, carry in enumerate(carries):
+                        if faults is not None and faults.fire(
+                                "calib_batch", uid=i) is not None:
+                            raise CalibrationError(
+                                f"injected calibration failure (block {i}, "
+                                f"batch {bi})", site="calib_batch")
+                        _, caps = block_cap(params, carry, i)
+                        with TraceAnnotation("prune.hessian_update"):
+                            _accumulate(accs, caps, plan, faults)
 
-        # ---- prune every linear in the block ------------------------------
-        for path in adapter.block_linear_paths(params, i):
-            if journal is not None and ordinal < journal.completed:
-                rec = journal.load(ordinal)
-                if tuple(rec.report.path) != tuple(path):
-                    raise ValueError(
-                        f"journal layer {ordinal} is "
-                        f"{path_str(rec.report.path)!r}, expected "
-                        f"{path_str(path)!r} — job dir belongs to a "
-                        "different model/plan")
-                if not rec.report.skipped:
-                    params = set_path(params, path, rec.kernel)
-                    if keep_masks and rec.mask is not None:
-                        masks[path] = rec.mask
-                accs.pop(path, None)
-                reports.append(rec.report)
-                ordinal += 1
-                if progress:
-                    progress(f"block {i} {path_str(path)}: journaled "
-                             f"(layer {ordinal - 1})")
-                continue
+                # ---- prune every linear in the block ----------------------
+                for path in adapter.block_linear_paths(params, i):
+                    if journal is not None and ordinal < journal.completed:
+                        rec = journal.load(ordinal)
+                        if tuple(rec.report.path) != tuple(path):
+                            raise ValueError(
+                                f"journal layer {ordinal} is "
+                                f"{path_str(rec.report.path)!r}, expected "
+                                f"{path_str(path)!r} — job dir belongs to a "
+                                "different model/plan")
+                        if not rec.report.skipped:
+                            params = set_path(params, path, rec.kernel)
+                            if keep_masks and rec.mask is not None:
+                                masks[path] = rec.mask
+                        accs.pop(path, None)
+                        reports.append(rec.report)
+                        ordinal += 1
+                        if progress:
+                            progress(f"block {i} {path_str(path)}: "
+                                     f"journaled (layer {ordinal - 1})")
+                        continue
 
-            t0 = time.perf_counter()
-            kernel = get_path(params, path)          # (in, out)
-            rule_idx, cfg = plan.resolve(path)
-            if cfg is None:                          # dense: skip + free H
-                accs.pop(path, None)
-                rep = LayerReport(
-                    path=path, sparsity=0.0, obs_loss=0.0,
-                    seconds=time.perf_counter() - t0, rule=rule_idx,
-                    tag="skip", params=int(kernel.size), skipped=True,
-                )
-                if journal is not None:
-                    journal.write(ordinal, rep, faults=faults)
-                reports.append(rep)
-                ordinal += 1
-                if progress:
-                    progress(f"block {i} {path_str(path)}: skipped "
-                             f"(rule {rule_idx})")
-                continue
-            acc = accs.get(path)
-            h = None
-            calib_skipped = 0
-            if acc is not None:
-                h = acc.finalize(
-                    min_count=(min_calib_samples
-                               if method_spec(cfg.method).data_aware else 0))
-                calib_skipped = int(float(acc.skipped))
-            pol = (plan.rules[rule_idx].on_singular
-                   if rule_idx >= 0 else "") or on_singular
-            res, guard = prune_layer_guarded(     # paper layout (out, in)
-                kernel.T, h, cfg, on_singular=pol,
-                max_escalations=max_escalations, solver=solver,
-                faults=faults, path=path_str(path))
-            accs.pop(path, None)                     # free the Hessian
-            new_kernel = res.weights.T.astype(kernel.dtype)
-            params = set_path(params, path, new_kernel)
-            mask_t = res.mask.T                      # (in, out), 1.0 = pruned
-            if keep_masks:
-                masks[path] = mask_t
-            rep = LayerReport(
-                path=path,
-                sparsity=float(jnp.mean(res.mask)),
-                obs_loss=float(res.loss),
-                seconds=time.perf_counter() - t0,
-                rule=rule_idx,
-                tag=cfg.tag(),
-                params=int(kernel.size),
-                damp_attempts=guard.damp_attempts,
-                percdamp_used=guard.percdamp_used,
-                fallback=guard.fallback,
-                calib_skipped=calib_skipped,
-            )
-            if journal is not None:
-                journal.write(ordinal, rep, kernel=new_kernel, mask=mask_t,
-                              faults=faults)
-            reports.append(rep)
-            ordinal += 1
-            if progress:
-                progress(f"block {i} {path_str(path)}: "
-                         f"sparsity={rep.sparsity:.3f} loss={rep.obs_loss:.3e}")
+                    new_kernel = mask_t = None
+                    with TraceAnnotation("prune.linear", path=path_str(path)):
+                        t0 = time.perf_counter()
+                        kernel = get_path(params, path)      # (in, out)
+                        rule_idx, cfg = plan.resolve(path)
+                        if cfg is None:              # dense: skip + free H
+                            accs.pop(path, None)
+                            rep = LayerReport(
+                                path=path, sparsity=0.0, obs_loss=0.0,
+                                seconds=time.perf_counter() - t0,
+                                rule=rule_idx, tag="skip",
+                                params=int(kernel.size), skipped=True,
+                            )
+                        else:
+                            acc = accs.get(path)
+                            h = None
+                            calib_skipped = 0
+                            if acc is not None:
+                                h = acc.finalize(min_count=(
+                                    min_calib_samples
+                                    if method_spec(cfg.method).data_aware
+                                    else 0))
+                                calib_skipped = int(float(acc.skipped))
+                            pol = (plan.rules[rule_idx].on_singular
+                                   if rule_idx >= 0 else "") or on_singular
+                            res, guard = prune_layer_guarded(  # (out, in)
+                                kernel.T, h, cfg, on_singular=pol,
+                                max_escalations=max_escalations,
+                                solver=solver, faults=faults,
+                                path=path_str(path))
+                            accs.pop(path, None)         # free the Hessian
+                            new_kernel = res.weights.T.astype(kernel.dtype)
+                            params = set_path(params, path, new_kernel)
+                            mask_t = res.mask.T      # (in, out), 1.0 = pruned
+                            if keep_masks:
+                                masks[path] = mask_t
+                            rep = LayerReport(
+                                path=path,
+                                sparsity=float(jnp.mean(res.mask)),
+                                obs_loss=float(res.loss),
+                                seconds=time.perf_counter() - t0,
+                                rule=rule_idx,
+                                tag=cfg.tag(),
+                                params=int(kernel.size),
+                                damp_attempts=guard.damp_attempts,
+                                percdamp_used=guard.percdamp_used,
+                                fallback=guard.fallback,
+                                calib_skipped=calib_skipped,
+                            )
+                    if journal is not None:
+                        journal.write(ordinal, rep, kernel=new_kernel,
+                                      mask=mask_t, faults=faults)
+                    reports.append(rep)
+                    ordinal += 1
+                    if progress:
+                        progress(f"block {i} {path_str(path)}: " + (
+                            f"skipped (rule {rule_idx})" if rep.skipped else
+                            f"sparsity={rep.sparsity:.3f} "
+                            f"loss={rep.obs_loss:.3e}"))
 
-        # ---- pass 2: propagate through the pruned block -------------------
-        carries = [block_fwd(params, carry, i) for carry in carries]
+                # ---- pass 2: propagate through the pruned block -----------
+                with TraceAnnotation("prune.propagate", block=i):
+                    carries = [block_fwd(params, carry, i)
+                               for carry in carries]
 
-    return params, PruneReport(
-        layers=reports, masks=masks, seconds=time.perf_counter() - t_start,
-        plan=plan,
-    )
+        return params, PruneReport(
+            layers=reports, masks=masks,
+            seconds=time.perf_counter() - t_start, plan=plan,
+        )
 
 
 def collect_hessian_stats(
@@ -408,10 +434,7 @@ def collect_hessian_stats(
     """
     batches = list(batches)
     carries = [adapter.prepare(params, b) for b in batches]
-    block_cap = jax.jit(
-        lambda p, c, i: adapter.block_apply(p, i, c, capture=True),
-        static_argnums=(2,),
-    )
+    _, block_cap = _block_jits(adapter)
     stats: dict[str, LayerStat] = {}
     accs: dict[Path, HessianAccumulator] = {}
     for i in range(adapter.num_blocks(params)):
@@ -419,13 +442,7 @@ def collect_hessian_stats(
         for carry in carries:
             out, caps = block_cap(params, carry, i)
             next_carries.append(out)
-            for path, x in caps.items():
-                valid = None
-                if isinstance(x, tuple):
-                    x, valid = x
-                if path not in accs:
-                    accs[path] = HessianAccumulator.init(x.shape[-1])
-                accs[path] = accs[path].update(x, valid)
+            _accumulate(accs, caps)
         carries = next_carries
         for path in adapter.block_linear_paths(params, i):
             if path not in accs:

@@ -51,7 +51,6 @@ auto-dispatch).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 import time
 from typing import Any
@@ -59,7 +58,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import obs
 from repro.kernels.ops import NmKernelConfig
 from repro.models import attention as A
 from repro.models import layers as L
@@ -79,6 +80,7 @@ class Request:
     done: bool = False
     # serving telemetry (time.perf_counter seconds; < 0 = not yet)
     t_submit: float = -1.0
+    t_admit: float = -1.0    # popped from the queue into a slot
     t_first: float = -1.0
     t_done: float = -1.0
     # wall-clock budget measured from t_submit (0 = none); expired requests
@@ -188,7 +190,7 @@ def _prefill_fn(model, params, cache, tokens, start):
                              (cache, init_logits))
 
 
-def _write_slot_fn(cache, row_cache, slot):
+def serve_write_slot(cache, row_cache, slot):
     """Scatter a batch=1 cache into row ``slot`` of the resident cache.
 
     Every traced cache leaf in the model zoo is batch-leading (GQA k/v +
@@ -247,15 +249,22 @@ def _model_jits(model, nm_kernel) -> dict:
     key = (id(model), nm_kernel)
     entry = _JIT_CACHE.get(key)
     if entry is None or entry["model"] is not model:   # id() reuse guard
+        # named functions, not partials: a trace shows each program as
+        # ``jit_<function name>`` (jit_serve_decode, jit_serve_prefill)
+        def serve_decode(params, cache, tokens, pos):
+            return _decode_fn(model, params, cache, tokens, pos)
+
+        def serve_prefill(params, cache, tokens, start):
+            return _prefill_fn(model, params, cache, tokens, start)
+
         # the resident cache is donated on both mutating steps (decode,
         # slot write): the engine always rebinds ``self._cache`` to the
         # output, and snapshot() materializes to host before capturing
         entry = {
             "model": model,      # strong ref pins id(model)
-            "decode": jax.jit(functools.partial(_decode_fn, model),
-                              donate_argnums=(1,)),
-            "prefill": jax.jit(functools.partial(_prefill_fn, model)),
-            "write_slot": jax.jit(_write_slot_fn, donate_argnums=(0,)),
+            "decode": jax.jit(serve_decode, donate_argnums=(1,)),
+            "prefill": jax.jit(serve_prefill),
+            "write_slot": jax.jit(serve_write_slot, donate_argnums=(0,)),
             # paged helpers: admission scatter donates the resident cache
             # (rebound immediately); the prefix gather reads cache and row
             # without donation — its outputs are fresh gather results, so
@@ -274,6 +283,7 @@ class ServingEngine:
     def __init__(self, model, params, cfg: ServeConfig, *, rng=None):
         if cfg.scheduler not in ("continuous", "wave"):
             raise ValueError(f"unknown scheduler {cfg.scheduler!r}")
+        obs.install()
         self.model = model
         self.cfg = cfg
         # compressed-resident: NmCompressed leaves stay compressed; they are
@@ -373,20 +383,22 @@ class ServingEngine:
 
     # ----------------------------------------------------------- main loop
     def submit(self, req: Request, *, force: bool = False):
-        if len(req.prompt) + 1 > self.cfg.max_len:
-            raise ValueError(
-                f"request {req.uid}: prompt length {len(req.prompt)} does "
-                f"not fit max_len={self.cfg.max_len} (need prompt + 1)")
-        if (not force and self.cfg.max_queued
-                and len(self.queue) >= self.cfg.max_queued):
-            # ~one queue drain per resident generation as the backoff hint
-            raise QueueFull(
-                f"request {req.uid} rejected: queue at max_queued="
-                f"{self.cfg.max_queued}",
-                retry_after_s=max(1.0, 0.1 * len(self.queue)))
-        if req.t_submit < 0:
-            req.t_submit = time.perf_counter()
-        self.queue.append(req)
+        with TraceAnnotation("serve.submit", uid=req.uid):
+            if len(req.prompt) + 1 > self.cfg.max_len:
+                raise ValueError(
+                    f"request {req.uid}: prompt length {len(req.prompt)} "
+                    f"does not fit max_len={self.cfg.max_len} (need "
+                    f"prompt + 1)")
+            if (not force and self.cfg.max_queued
+                    and len(self.queue) >= self.cfg.max_queued):
+                # ~one queue drain per resident generation as the backoff hint
+                raise QueueFull(
+                    f"request {req.uid} rejected: queue at max_queued="
+                    f"{self.cfg.max_queued}",
+                    retry_after_s=max(1.0, 0.1 * len(self.queue)))
+            if req.t_submit < 0:
+                req.t_submit = time.perf_counter()
+            self.queue.append(req)
 
     def idle(self) -> bool:
         """No queued requests and no slot mid-generation."""
@@ -425,21 +437,22 @@ class ServingEngine:
     def pump(self) -> bool:
         """Process one scheduling quantum — one decode step (continuous) or
         one whole wave (wave).  Returns False when there is nothing to do."""
-        self._expire_deadlines()
-        with L.nm_kernel_scope(self.nm_kernel):
-            if self.cfg.scheduler == "wave":
-                wave = self._next_wave()
-                if not wave:
-                    return False
-                self._serve_wave(wave)
-                now = time.perf_counter()
-                for req in wave:
-                    req.done = True
-                    if req.t_done < 0:
-                        req.t_done = now
-                    self.finished.append(req)
-                return True
-            return self._continuous_step()
+        with TraceAnnotation("serve.pump", step=self.stats["decode_steps"]):
+            self._expire_deadlines()
+            with L.nm_kernel_scope(self.nm_kernel):
+                if self.cfg.scheduler == "wave":
+                    wave = self._next_wave()
+                    if not wave:
+                        return False
+                    self._serve_wave(wave)
+                    now = time.perf_counter()
+                    for req in wave:
+                        req.done = True
+                        if req.t_done < 0:
+                            req.t_done = now
+                        self.finished.append(req)
+                    return True
+                return self._continuous_step()
 
     def run(self, *, max_steps: int = 100_000) -> list[Request]:
         """Drain queue and slots; returns finished requests in uid order.
@@ -514,47 +527,55 @@ class ServingEngine:
             except PoolExhausted:
                 return False
         self.queue.pop(0)
-        row = self.model.init_cache(1, self.cfg.max_len)
-        start = 0
-        if plan is not None:
-            start = plan.start
-            if plan.n_shared_tok:
-                pids = np.full(self._pps, SCRATCH, np.int32)
-                pids[:len(plan.gather_pids)] = plan.gather_pids
-                row = self._prefix_row(self._cache, row, jnp.asarray(pids),
-                                       jnp.int32(plan.n_shared_tok))
-                self.stats["prefix_hit_tokens"] += plan.n_shared_tok
-        row, last = self._prefill(self.params, row,
-                                  jnp.asarray(tokens_all)[None, :], start)
-        if plan is not None:
-            lps = np.zeros(self._pps, np.int32)
-            pids = np.full(self._pps, SCRATCH, np.int32)
-            lps[:len(plan.fresh_lps)] = plan.fresh_lps
-            pids[:len(plan.fresh_pids)] = plan.fresh_pids
-            self._cache = self._admit_write(self._cache, row, jnp.int32(slot),
-                                            jnp.asarray(lps),
-                                            jnp.asarray(pids))
-            self.pager.register(slot, prompt)
-        else:
-            self._cache = self._write_slot(self._cache, row, slot)
-        self.stats["prefills"] += 1
-        self.stats["prefill_tokens"] += S - start
-        self.stats["vtime"] += S - start
-        self._slots[slot] = req
-        self._slot_seq[slot] = self._seq
-        self._seq += 1
-        if resumed:
-            self._tokens[slot, 0] = int(tokens_all[-1])
-            self._pos[slot] = S - 1     # re-decode the last emitted token
+        req.t_admit = time.perf_counter()
+        with TraceAnnotation("serve.admit", uid=req.uid, slot=slot, tokens=S):
+            with TraceAnnotation("serve.row_init"):
+                row = self.model.init_cache(1, self.cfg.max_len)
+            start = 0
+            if plan is not None:
+                start = plan.start
+                if plan.n_shared_tok:
+                    pids = np.full(self._pps, SCRATCH, np.int32)
+                    pids[:len(plan.gather_pids)] = plan.gather_pids
+                    row = self._prefix_row(self._cache, row,
+                                           jnp.asarray(pids),
+                                           jnp.int32(plan.n_shared_tok))
+                    self.stats["prefix_hit_tokens"] += plan.n_shared_tok
+            with TraceAnnotation("serve.prefill"):
+                row, last = self._prefill(self.params, row,
+                                          jnp.asarray(tokens_all)[None, :],
+                                          start)
+            with TraceAnnotation("serve.write_slot"):
+                if plan is not None:
+                    lps = np.zeros(self._pps, np.int32)
+                    pids = np.full(self._pps, SCRATCH, np.int32)
+                    lps[:len(plan.fresh_lps)] = plan.fresh_lps
+                    pids[:len(plan.fresh_pids)] = plan.fresh_pids
+                    self._cache = self._admit_write(
+                        self._cache, row, jnp.int32(slot), jnp.asarray(lps),
+                        jnp.asarray(pids))
+                    self.pager.register(slot, prompt)
+                else:
+                    self._cache = self._write_slot(self._cache, row, slot)
+            self.stats["prefills"] += 1
+            self.stats["prefill_tokens"] += S - start
+            self.stats["vtime"] += S - start
+            self._slots[slot] = req
+            self._slot_seq[slot] = self._seq
+            self._seq += 1
+            if resumed:
+                self._tokens[slot, 0] = int(tokens_all[-1])
+                self._pos[slot] = S - 1     # re-decode the last emitted token
+                return True
+            with TraceAnnotation("serve.first_token"):
+                tok = int(np.asarray(self._select(last))[0])
+            self._absorb(req, tok)
+            self._tokens[slot, 0] = tok
+            self._pos[slot] = S
+            if req.done or S + 1 >= self.cfg.max_len:
+                req.done = True
+                self._retire(slot)      # freed — caller retries the queue
             return True
-        tok = int(np.asarray(self._select(last))[0])
-        self._absorb(req, tok)
-        self._tokens[slot, 0] = tok
-        self._pos[slot] = S
-        if req.done or S + 1 >= self.cfg.max_len:
-            req.done = True
-            self._retire(slot)          # freed — caller retries the queue
-        return True
 
     def _admit(self) -> bool:
         """Fill free slots from the queue (prefill-into-slot).  The whole
@@ -651,9 +672,10 @@ class ServingEngine:
             used = self.pager.pool.used_pages
             if used > self.stats["pages_hwm"]:
                 self.stats["pages_hwm"] = used
-        logits, self._cache = self._decode(
-            self.params, self._cache,
-            jnp.asarray(self._tokens), jnp.asarray(self._pos))
+        with TraceAnnotation("serve.decode"):
+            logits, self._cache = self._decode(
+                self.params, self._cache,
+                jnp.asarray(self._tokens), jnp.asarray(self._pos))
         if self.faults is not None:
             stall = self.faults.fire("decode_stall")
             if stall is not None:
@@ -667,23 +689,25 @@ class ServingEngine:
             raise NonFiniteLogits(
                 f"decode step {self.stats['decode_steps']} produced "
                 f"non-finite logits", site="decode_logits")
-        nxt = np.asarray(self._select(logits))
+        with TraceAnnotation("serve.sample"):    # the host waits here
+            nxt = np.asarray(self._select(logits))
         self.stats["decode_steps"] += 1
         self.stats["busy_slot_steps"] += len(active)
         self.stats["vtime"] += 1
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue
-            self._absorb(req, int(nxt[slot]))
-            self._tokens[slot, 0] = nxt[slot]
-            # truncate exactly where the wave oracle does: the last decode
-            # position is max_len - 2 (horizon = max_len - S - 1)
-            if not req.done and self._pos[slot] + 2 >= self.cfg.max_len:
-                req.done = True              # slot cache region exhausted
-            if req.done:
-                self._retire(slot)
-            else:
-                self._pos[slot] += 1
+        with TraceAnnotation("serve.absorb"):
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                self._absorb(req, int(nxt[slot]))
+                self._tokens[slot, 0] = nxt[slot]
+                # truncate exactly where the wave oracle does: the last
+                # decode position is max_len - 2 (horizon = max_len - S - 1)
+                if not req.done and self._pos[slot] + 2 >= self.cfg.max_len:
+                    req.done = True          # slot cache region exhausted
+                if req.done:
+                    self._retire(slot)
+                else:
+                    self._pos[slot] += 1
         if self.cfg.debug_checks and self.pager is not None:
             self.pager.check()
         return True

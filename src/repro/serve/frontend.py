@@ -10,7 +10,11 @@ Endpoints:
   GET  /healthz   → {"ok": ..., "queued": q, "active": a, ...}; when a
                   supervisor wraps the engine this reflects its health
                   state machine ("healthy"/"degraded"/"recovering").
-  GET  /stats     → engine.stats (+ supervisor stats) as JSON
+  GET  /stats     → engine.stats (+ supervisor stats) as JSON, with the
+                  process's compile and garbage-collection counters
+                  (``repro.obs``) under "jit" and "gc": a ``lowerings``
+                  count that grows while serving is a retrace, and
+                  ``gc_pause_max_s`` the longest collection pause.
 
 Threading model: the engine is single-threaded compute, so every engine
 touch (submit / cancel / pump) happens under one lock.  ``pump()`` runs in
@@ -48,6 +52,7 @@ from typing import Any
 
 import numpy as np
 
+from repro import obs
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.faults import FaultPlan, QueueFull
 
@@ -160,6 +165,7 @@ class HttpFrontend:
                             **{k: v for k, v in
                                self.supervisor.stats.items()},
                             "state": self.supervisor.state}
+                stats["jit"], stats["gc"] = obs.jit_counts(), obs.gc_counts()
                 self._json(writer, stats)
             else:
                 self._json(writer, {"error": "not found"}, status=404)

@@ -10,7 +10,9 @@ This is the only test file that describes the chip.
 """
 from __future__ import annotations
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +73,21 @@ def test_nm_kernel_compiles_for_v5e(one_chip, for_the_chip, c, b, batch):
     compiled = _compile(lambda x, p: ops.nm_matmul(x, p, impl="pallas"),
                         x, packed)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_nm_kernel_keeps_its_name_in_the_compiled_program(one_chip,
+                                                          for_the_chip):
+    """A profile names each device op after its HLO instruction; the
+    benchmark finds the kernel's calls as ``%nm_matmul.<n> = ...``."""
+    n, m, c, b = 2, 4, 640, 2560
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    packed = NmCompressed(values=sds((m - n, c, b // m), jnp.bfloat16),
+                          indices=sds((1, c, b // m), jnp.int8),
+                          n=n, m=m, b=b, idx_bits=4)
+    compiled = _compile(lambda x, p: ops.nm_matmul(x, p, impl="pallas"),
+                        sds((4, b), jnp.bfloat16), packed)
+    assert re.search(r"%nm_matmul(\.\d+)? = \S+ custom-call\(",
+                     compiled.as_text())
 
 
 @pytest.mark.parametrize("b", [2560, 6912])
