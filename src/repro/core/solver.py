@@ -18,17 +18,26 @@ batched **Cholesky** solve (one factorization + two triangular solves per row
 instead of a general LU with pivoting).
 
 Appendix H.2 (GPU memory limits) is honored through ``row_chunk``: rows are
-processed in vertical chunks so the (chunk, r_max, r_max) systems and gathers
-stay bounded.
+processed in vertical chunks so the (chunk, r_max, r_max) systems and
+selections stay bounded.
 
-TPU note: the final weight update is *not* applied per-row as ``λ̂ @ R``
-(a (r_max, b)-gather per row).  We scatter the multipliers into a dense
-matrix Λ and compute ``Δ = -Λ @ Hinv`` — one MXU matmul, no per-row gathers.
-Algebraically identical because R's rows are rows of Hinv.  The block-wise
-hot path (``prune_block``) exploits one more structural fact: every pruned
-index of block j₁ lies inside ``[j1, j1+B)``, so Λ has at most B nonzero
-*columns* and the update only ever reads **B rows** of Hinv — the matmul is
-``(c, B) @ (B, b)``, a b/B-fold flop reduction over the dense form.
+TPU note: the systems are built by **one-hot contraction**, not by gather.
+Each row's padded indices become a 0/1 selection matrix P (c, r_max, n), and
+u' = P·w, R̂' = P·Hinv·Pᵀ, Λ = λ̂·P are MXU matmuls.  A per-element gather
+``hinv[q[:, :, None], q[:, None, :]]`` fetches every entry of R̂ as its own
+scalar from HBM, and took 83% of the prune job's solve on a v5e.  The
+contractions run at ``Precision.HIGHEST``: with one operand exactly 0/1 each
+output has one nonzero term, so they reproduce the gathered values bit for
+bit (TPU DEFAULT would round Hinv to bf16).  The weight update is then
+``Δ = -Λ @ Hinv`` — one MXU matmul, no per-row gathers — algebraically
+identical to λ̂ @ R because R's rows are rows of Hinv.
+
+The block-wise hot path (``prune_block``) exploits one structural fact:
+every pruned index of block j₁ lies inside ``[j1, j1+B)``.  So P has width
+B and R̂' reads only the (B, B) diagonal block of Hinv (the selection costs
+c·(2rB² + 2r²B) flops), Λ has at most B nonzero columns, and the
+update reads only **B rows** of Hinv — the matmul is ``(c, B) @ (B, b)``, a
+b/B-fold flop reduction over the dense form.
 """
 from __future__ import annotations
 
@@ -38,25 +47,31 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
-def _padded_system(
-    hinv: Array,      # (b, b) trailing inverse Hessian (embedded full-size OK)
-    w: Array,         # (c, b) current weights (same column space as hinv)
-    q_abs: Array,     # (c, r_max) int32 absolute column indices, padded
-    valid: Array,     # (c, r_max) bool
-) -> tuple[Array, Array]:
-    """Build the padded per-row systems (R̂', u') of Appendix H.1."""
-    # u' — padded pruned-weight values (Eq. 77)
-    u = jnp.take_along_axis(w, q_abs, axis=1)                    # (c, r_max)
-    u = jnp.where(valid, u, 0.0)
+# Every contraction with a 0/1 selection matrix: exact, so bit-equal to a gather.
+_EXACT = jax.lax.Precision.HIGHEST
 
-    # R̂' — (c, r_max, r_max) with identity padding (Eq. 78)
-    rhat = hinv[q_abs[:, :, None], q_abs[:, None, :]]            # (c, r, r)
-    both = valid[:, :, None] & valid[:, None, :]
-    eye = jnp.eye(q_abs.shape[1], dtype=hinv.dtype)[None]
-    rhat = jnp.where(both, rhat, 0.0) + jnp.where(
-        (~valid[:, :, None]) & (~valid[:, None, :]), eye, 0.0
-    )
-    return rhat, u
+
+def _padded_system(
+    h: Array,         # (n, n) inverse Hessian on the columns q indexes
+    w: Array,         # (c, n) current weights on the same columns
+    q: Array,         # (c, r_max) int32 column indices into h, padded
+    valid: Array,     # (c, r_max) bool
+) -> tuple[Array, Array, Array]:
+    """Build the padded per-row systems of Appendix H.1: (P, R̂', u').
+
+    P (c, r_max, n) is the one-hot selection of each row's indices, zero in
+    padded slots; u' = P·w (Eq. 77) and R̂' = P·h·Pᵀ with the identity in
+    the padded corner (Eq. 78).
+    """
+    sel = (q[..., None] == jnp.arange(h.shape[0])) & valid[..., None]
+    u = jnp.einsum("crk,ck->cr", sel, w, precision=_EXACT)
+    rhat = jnp.einsum("crl,csl->crs",
+                      jnp.einsum("crk,kl->crl", sel, h, precision=_EXACT),
+                      sel, precision=_EXACT)
+    pad = ~valid
+    eye = jnp.eye(q.shape[1], dtype=h.dtype)[None]
+    rhat = rhat + jnp.where(pad[:, :, None] & pad[:, None, :], eye, 0.0)
+    return sel, rhat, u
 
 
 _TRI_BASE = 16
@@ -113,8 +128,11 @@ def _spd_solve(rhat: Array, u: Array) -> Array:
     break tracing inside ``dist.prune.prune_layer_sharded``.
     """
     linv = _tri_inv_lower(jnp.linalg.cholesky(rhat))
-    y = jnp.einsum("...rs,...s->...r", linv, u)
-    return jnp.einsum("...sr,...s->...r", linv, y)
+    # Products and sums in f32, not einsums: at DEFAULT precision the TPU
+    # compiler may put a batched matvec on the MXU with bf16 operands or
+    # keep it on the VPU in f32, depending on the program around it.
+    y = jnp.sum(linv * u[..., None, :], axis=-1)
+    return jnp.sum(linv * y[..., :, None], axis=-2)
 
 
 def solution_finite(*arrays: Array) -> bool:
@@ -127,67 +145,62 @@ def solution_finite(*arrays: Array) -> bool:
     return all(bool(jnp.all(jnp.isfinite(a))) for a in arrays)
 
 
+def _solve_rows(
+    h: Array, w: Array, q: Array, valid: Array
+) -> tuple[Array, Array, Array, Array]:
+    """Solve the rows' padded systems on the columns of ``h``.
+
+    Returns λ̂ (c, r_max) (exactly zero in padded slots, Eq. 79), u', the
+    multipliers scattered to their columns Λ = λ̂·P (c, n), and the (c, n)
+    bool mask of pruned columns.
+    """
+    sel, rhat, u = _padded_system(h, w, q, valid)
+    # R̂ is symmetric positive definite (principal submatrix of an SPD
+    # inverse Hessian, identity in the padded corner) — Cholesky applies.
+    lam = jnp.where(valid, _spd_solve(rhat, u), 0.0)
+    lam_dense = jnp.einsum("cr,crk->ck", lam, sel, precision=_EXACT)
+    return lam, u, lam_dense, jnp.any(sel, axis=1)
+
+
+def _solve_rows_chunked(
+    h: Array, w: Array, q: Array, valid: Array, row_chunk: int
+) -> tuple[Array, Array, Array, Array]:
+    """``_solve_rows``, chunked over rows when requested (Appendix H.2)."""
+    c = w.shape[0]
+    if row_chunk and c > row_chunk and c % row_chunk == 0:
+        n = c // row_chunk
+        out = jax.lax.map(
+            lambda args: _solve_rows(h, *args),
+            (
+                w.reshape(n, row_chunk, -1),
+                q.reshape(n, row_chunk, -1),
+                valid.reshape(n, row_chunk, -1),
+            ),
+        )
+        return tuple(x.reshape(c, -1) for x in out)
+    return _solve_rows(h, w, q, valid)
+
+
 def batched_multipliers(
     hinv: Array, w: Array, q_abs: Array, valid: Array
 ) -> Array:
     """Solve all rows' padded systems; return multipliers λ̂ (c, r_max)."""
-    rhat, u = _padded_system(hinv, w, q_abs, valid)
-    # R̂ is symmetric positive definite (principal submatrix of an SPD
-    # inverse Hessian, identity in the padded corner) — Cholesky applies.
-    lam = _spd_solve(rhat, u)
-    return jnp.where(valid, lam, 0.0)
-
-
-def _multipliers_chunked(
-    hinv: Array, w: Array, q_abs: Array, valid: Array, row_chunk: int
-) -> Array:
-    """λ̂ for all rows, chunked over rows when requested (Appendix H.2)."""
-    c = w.shape[0]
-    if row_chunk and c > row_chunk and c % row_chunk == 0:
-        n = c // row_chunk
-        return jax.lax.map(
-            lambda args: batched_multipliers(hinv, *args),
-            (
-                w.reshape(n, row_chunk, -1),
-                q_abs.reshape(n, row_chunk, -1),
-                valid.reshape(n, row_chunk, -1),
-            ),
-        ).reshape(c, -1)
-    return batched_multipliers(hinv, w, q_abs, valid)
-
-
-def apply_update(
-    hinv: Array,      # (b, b)
-    w: Array,         # (c, b)
-    q_abs: Array,     # (c, r_max)
-    valid: Array,     # (c, r_max)
-    lam: Array,       # (c, r_max)
-) -> Array:
-    """Δ = -Λ_scatter @ Hinv ; returns updated weights (c, b).
-
-    Pruned positions are additionally zeroed exactly (the analytic update
-    already sends them to 0; we clamp against fp roundoff).
-    """
-    c, b = w.shape
-    lam_dense = jnp.zeros((c, b), dtype=hinv.dtype)
-    # scatter-add handles (impossible) duplicate padded indices benignly
-    lam_dense = lam_dense.at[jnp.arange(c)[:, None], q_abs].add(
-        jnp.where(valid, lam, 0.0)
-    )
-    w_new = w - lam_dense @ hinv
-    # exact zeros at pruned coordinates
-    prune_hit = jnp.zeros((c, b), dtype=bool).at[
-        jnp.arange(c)[:, None], q_abs
-    ].max(valid)
-    return jnp.where(prune_hit, 0.0, w_new)
+    return _solve_rows(hinv, w, q_abs, valid)[0]
 
 
 def prune_rows_block(
     hinv: Array, w: Array, q_abs: Array, valid: Array, *, row_chunk: int = 0
 ) -> Array:
-    """Full padded solve + update, optionally chunked over rows (App. H.2)."""
-    lam = _multipliers_chunked(hinv, w, q_abs, valid, row_chunk)
-    return apply_update(hinv, w, q_abs, valid, lam)
+    """Full padded solve + update Δ = -Λ @ Hinv, optionally chunked over
+    rows (App. H.2); returns updated weights (c, b).
+
+    Pruned positions are additionally zeroed exactly (the analytic update
+    already sends them to 0; we clamp against fp roundoff).
+    """
+    _, _, lam_dense, prune_hit = _solve_rows_chunked(
+        hinv, w, q_abs, valid, row_chunk
+    )
+    return jnp.where(prune_hit, 0.0, w - lam_dense @ hinv)
 
 
 def prune_block(
@@ -203,39 +216,38 @@ def prune_block(
     """Single-solve OBS for one column block: (updated weights, Σ_rows S_k).
 
     The multipliers are solved **once** and reused for both the loss
-    (S = ½ u R̂⁻¹ uᵀ = ½ λ̂·u, Eq. 61) and the weight update — the loop in
-    core/thanos.py previously built and solved the identical padded systems
-    twice per block.  Because every pruned index lies inside the block, the
-    dense scatter-matmul of ``apply_update`` collapses to
-    ``(c, B) @ Hinv[j1:j1+B, :]``.
+    (S = ½ u R̂⁻¹ uᵀ = ½ λ̂·u, Eq. 61) and the weight update.
+
+    Every pruned index lies inside the block, so everything is built from
+    the block's slice: u' from ``W[:, s:s+B]`` and R̂' from the diagonal
+    block ``Hinv[s:s+B, s:s+B]`` by one-hot contraction with the (c, r_max,
+    B) selection P (module TPU note: exact, and MXU matmuls where a gather
+    from the whole (b, b) inverse would fetch each entry alone), and the
+    update is ``Δ = -(λ̂·P) @ Hinv[s:s+B, :]``.
 
     Columns left of j1 are masked out of the update: they are already
     processed (mathematically Hinv rows j1:j1+B are zero there; the
     incremental downdate that produces ``hinv`` leaves O(ε) residue which
     must not perturb — or un-zero — finished columns).
 
-    A ragged last block (b % B ≠ 0) is handled by anchoring the B-row
-    slice at ``min(j1, b - B)``: the extra leading rows carry λ̂ = 0 and
+    A ragged last block (b % B ≠ 0) is handled by anchoring the slices at
+    s = ``min(j1, b - B)``: the extra leading rows carry λ̂ = 0 and
     contribute nothing.
     """
     c, b = w.shape
-    lam = _multipliers_chunked(hinv, w, q_abs, valid, row_chunk)
-    u = jnp.where(valid, jnp.take_along_axis(w, q_abs, axis=1), 0.0)
+    start = jnp.minimum(j1, b - block_size)   # == j1 except ragged last block
+    hinv_rows = jax.lax.dynamic_slice(hinv, (start, 0), (block_size, b))
+    h_blk = jax.lax.dynamic_slice(hinv_rows, (0, start),
+                                  (block_size, block_size))
+    w_blk = jax.lax.dynamic_slice(w, (0, start), (c, block_size))
+    lam, u, lam_blk, prune_hit = _solve_rows_chunked(
+        h_blk, w_blk, q_abs - start, valid, row_chunk
+    )
     loss = 0.5 * jnp.sum(lam * u)
 
-    start = jnp.minimum(j1, b - block_size)   # == j1 except ragged last block
-    q_rel = q_abs - start
-    # invalid slots carry λ̂ = 0 / valid = False, so their scatter is a no-op
-    lam_blk = jnp.zeros((c, block_size), dtype=hinv.dtype).at[
-        jnp.arange(c)[:, None], q_rel
-    ].add(jnp.where(valid, lam, 0.0))
-    hinv_rows = jax.lax.dynamic_slice(hinv, (start, 0), (block_size, b))
     delta = lam_blk @ hinv_rows
     delta = jnp.where(jnp.arange(b)[None, :] >= j1, delta, 0.0)
     w_new = w - delta
-    prune_hit = jnp.zeros((c, block_size), dtype=bool).at[
-        jnp.arange(c)[:, None], q_rel
-    ].max(valid)
     w_new = jnp.where(
         jax.lax.dynamic_update_slice(
             jnp.zeros((c, b), dtype=bool), prune_hit, (0, start)
@@ -255,6 +267,5 @@ def obs_loss(hinv: Array, w: Array, q_abs: Array, valid: Array) -> Array:
     Standalone diagnostic: the block-wise hot path gets the loss for free
     from ``prune_block``'s single solve.
     """
-    lam = batched_multipliers(hinv, w, q_abs, valid)
-    u = jnp.where(valid, jnp.take_along_axis(w, q_abs, axis=1), 0.0)
+    lam, u, _, _ = _solve_rows(hinv, w, q_abs, valid)
     return 0.5 * jnp.sum(lam * u, axis=1)

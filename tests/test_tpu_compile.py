@@ -1,4 +1,5 @@
-"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+"""Compile the main-path Pallas kernels, and one block of the Thanos solve,
+for a described TPU v5e chip.
 
 Nothing runs: each test lowers and compiles one kernel at h2o-danube-1.8b's
 real widths for a v5e chip that is described, not attached, so Mosaic
@@ -11,6 +12,7 @@ This is the only test file that describes the chip.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import re
 
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import solver
 from repro.core.sparsity import NmCompressed
 from repro.kernels import ops
 
@@ -94,3 +97,27 @@ def test_nm_kernel_keeps_its_name_in_the_compiled_program(one_chip,
 def test_hessian_kernel_compiles_for_v5e(one_chip, for_the_chip, b):
     x = jax.ShapeDtypeStruct((2048, b), jnp.bfloat16, sharding=one_chip)
     _compile(lambda x: ops.hessian_xtx(x, impl="pallas"), x)
+
+
+@pytest.mark.parametrize("c,b", [(6912, 2560), (2560, 6912)])
+def test_prune_block_selects_without_elementwise_gather(one_chip, c, b):
+    """One 2:4 block of the Thanos solve (B 128, r_max 64) at danube's gate
+    and down shapes builds each row's (r_max, r_max) system by contraction:
+    no gather in the program yields c·64·64 elements, the per-element fetch
+    from the (b, b) inverse that once took 83% of the solve on a v5e.  And
+    the solve's matvecs stay in f32: no default-precision (bf16-operand)
+    convolution yields a (c, 1, r_max) or (c, r_max, 1) result."""
+    r_max, B = 64, 128
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(
+        lambda h, w, q, v, j1: solver.prune_block(h, w, q, v, j1, B)
+    ).lower(sds((b, b), jnp.float32), sds((c, b), jnp.float32),
+            sds((c, r_max), jnp.int32), sds((c, r_max), jnp.bool_),
+            sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    gathers = re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text)
+    sizes = [math.prod(int(d) for d in g.split(",") if d) for g in gathers]
+    assert c * r_max * r_max not in sizes
+    matvec = re.compile(rf"= f32\[{c},(1,{r_max}|{r_max},1)\]\S* convolution\(")
+    assert not [line for line in text.splitlines()
+                if matvec.search(line) and "operand_precision" not in line]
