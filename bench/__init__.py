@@ -11,7 +11,14 @@ runs one cell once.  Everything a cell is made of is found by name:
                                read by the driver its ``kind`` names;
 * ``metrics/<metric>.py``     one reader per metric, ``read(rec)``;
 * ``references/<name>.py``    plain fp32 references, importing nothing of
-                               the program.
+                               the program: ``block_leaves(cfg, i)`` and
+                               ``top_leaves(cfg)`` name and size the
+                               weights (block ``i``'s own, so blocks may
+                               differ), ``LINEARS`` the kernels masked and
+                               packed n:m ((in, out), or (E, in, out) for a
+                               stack of experts), and ``block``, ``head``
+                               and ``magnitude_nm_mask`` (of one 2-D kernel)
+                               compute.
 
 The peak table (``peaks.py``), the operation and byte counts
 (``costs.py``), the trace reduction (``trace.py``) and the comparisons that

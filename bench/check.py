@@ -34,7 +34,11 @@ F32 = jnp.float32
 # --------------------------------------------------------------------------
 def _prep(ref, nm):
     """fp32 copy of a block's weights, n:m-masked by the reference's own
-    magnitude rule when the cell serves n:m weights."""
+    magnitude rule when the cell serves n:m weights: a 2-D kernel whole, a
+    stack of experts (E, in, out) one expert at a time."""
+
+    def mask(v):
+        return ref.magnitude_nm_mask(v, *nm)
 
     @jax.jit
     def prep(flat):
@@ -42,7 +46,8 @@ def _prep(ref, nm):
         for k, v in flat.items():
             v = v.astype(F32)
             if nm and k in ref.LINEARS:
-                v = jnp.where(ref.magnitude_nm_mask(v, *nm), 0.0, v)
+                v = jnp.where(jax.vmap(mask)(v) if v.ndim == 3 else mask(v),
+                              0.0, v)
             out[k] = v
         return out
 
@@ -80,7 +85,7 @@ def served_gaps(cfg: dict, seed: int, requests: list, max_len: int,
     top = prep(W.make(seed, W.TOP, ref.top_leaves(cfg), cfg["dtype"]))
     h = {k: [top["embed/table"][jnp.asarray(s)] for s in seqs] for k in kinds}
     for i in range(cfg["num_layers"]):
-        wf = prep(W.make(seed, i, ref.block_leaves(cfg), cfg["dtype"]))
+        wf = prep(W.make(seed, i, ref.block_leaves(cfg, i), cfg["dtype"]))
         for k in kinds:
             h[k] = [block[k](x, wf) for x in h[k]]
         del wf
@@ -147,7 +152,8 @@ def prune_reference(cfg: dict, seed: int, block: int, tokens: np.ndarray,
 
     Calibration sequences run one at a time through the embedding and the
     blocks up to ``block`` (only block 0 is supported: the cells prune the
-    first block).  ``quant`` makes it the fp8 control: activations rounded
+    first block), and each of its 2-D linears is pruned under the Hessian
+    of its input.  ``quant`` makes it the fp8 control: activations rounded
     per token before the Hessian, weights per output channel before the
     solve."""
     if block != 0:
@@ -156,18 +162,20 @@ def prune_reference(cfg: dict, seed: int, block: int, tokens: np.ndarray,
                         if isinstance(v, (int, float, str))))
     ref, acc, solve, err = _prune_fns(cfg["reference"], keys)
     top = W.make(seed, W.TOP, ref.top_leaves(cfg), cfg["dtype"])
+    leaves = dict(ref.block_leaves(cfg, block))
     wf = jax.tree.map(lambda v: v.astype(F32),
-                      W.make(seed, block, ref.block_leaves(cfg), cfg["dtype"]))
+                      W.make(seed, block, leaves.items(), cfg["dtype"]))
     table = top["embed/table"]
-    d, f = cfg["d_model"], cfg["d_ff"]
-    q = cfg["num_heads"] * cfg["head_dim"]
-    hs = {"attn_in": jnp.zeros((d, d), F32), "wo_in": jnp.zeros((q, q), F32),
-          "mlp_in": jnp.zeros((d, d), F32), "down_in": jnp.zeros((f, f), F32)}
+    linears = [k for k in ref.LINEARS if k in leaves]
+    if any(len(leaves[k]) != 2 for k in linears):
+        raise ValueError("the reference prunes 2-D kernels only")
+    hs = {ref.LINEAR_INPUT[k]: jnp.zeros((leaves[k][0],) * 2, F32)
+          for k in linears}
     for s in tokens:
         hs = acc(hs, table[jnp.asarray(s)].astype(F32), wf, quant=quant)
     hs = {k: 2.0 * v / tokens.size for k, v in hs.items()}
     out = {}
-    for name in ref.LINEARS:
+    for name in linears:
         w0 = wf[name].T
         wq = ref.fp8(w0, 1) if quant else w0
         w1, mask, loss = solve(wq, hs[ref.LINEAR_INPUT[name]], n=job["n"],

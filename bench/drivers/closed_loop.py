@@ -81,16 +81,14 @@ def row_chunk(c: int, g: int, budget: int = 2 ** 21) -> int:
                       if c % r == 0 and r * g <= budget])
 
 
-@functools.partial(jax.jit, static_argnames=("n", "m"))
-def _compress_linear(kernel, *, n, m):
-    """An (in, out) kernel masked n:m by magnitude (core/magnitude.py) and
-    packed compressed-resident (core/sparsity.pack_nm), rows at a time;
-    masking and packing are row by row, so this equals one call on the
-    whole weight."""
+def _pack_rows(w, n, m):
+    """(values, indices) of a (c, b) weight, masked n:m by magnitude
+    (core/magnitude.py) and packed compressed-resident
+    (core/sparsity.pack_nm), rows at a time; masking and packing are row
+    by row, so this equals one call on the whole weight."""
     from repro.core.magnitude import prune_nm
-    from repro.core.sparsity import NmCompressed, pack_nm
+    from repro.core.sparsity import pack_nm
 
-    w = kernel.T                                       # (c, b) paper layout
     c, b = w.shape
     rows = row_chunk(c, b // m)
 
@@ -103,30 +101,61 @@ def _compress_linear(kernel, *, n, m):
     def join(x):                       # (chunks, planes, rows, g) → (planes, c, g)
         return jnp.moveaxis(x, 0, 1).reshape(x.shape[1], c, -1)
 
-    return NmCompressed(join(vals), join(idx), n, m, b, 4)
+    return join(vals), join(idx)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "m"))
+def _compress_linear(kernel, *, n, m):
+    """An (in, out) kernel as the program's ``NmCompressed``."""
+    from repro.core.sparsity import NmCompressed
+
+    return NmCompressed(*_pack_rows(kernel.T, n, m), n, m, kernel.shape[0], 4)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "m"))
+def _compress_stacked(kernel, *, n, m):
+    """A stack of experts (E, in, out) as the program's
+    ``NmStackedCompressed``: each expert masked and packed as
+    ``_compress_linear`` does a 2-D kernel, one expert after another."""
+    from repro.core.sparsity import NmStackedCompressed
+
+    vals, idx = jax.lax.map(lambda k: _pack_rows(k.T, n, m), kernel)
+    e, b, _ = kernel.shape
+    return NmStackedCompressed(vals, idx, n, m, b, e, 4)
+
+
+def compress(kernel, n: int, m: int):
+    """A kernel the reference lists in ``LINEARS``, packed n:m: 2-D
+    (in, out), or 3-D (E, in, out) for a stack of experts."""
+    pack = _compress_stacked if kernel.ndim == 3 else _compress_linear
+    return pack(kernel, n=n, m=m)
 
 
 def build(cfg: dict, seed: int):
-    """(model, params) of ``cfg`` with weights from ``seed``."""
+    """(model, params) of ``cfg`` with weights from ``seed``, each block
+    made from its own leaves (blocks may differ, as a leading dense layer
+    differs from the expert layers after it)."""
     from repro.models.model_builder import build_model
 
     ref = harness.reference(cfg["reference"])
     model = build_model(harness.model_config(cfg))
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    want = dict(ref.block_leaves(cfg))
-    got = {k: tuple(v.shape) for k, v in
-           W.flatten(abstract["blocks"][0]).items()}
-    if got != want:
-        raise harness.SpecError(f"program block leaves {got} differ from "
-                                f"the reference's {want}")
+    leaves = [dict(ref.block_leaves(cfg, i)) for i in range(cfg["num_layers"])]
+    for i, want in enumerate(leaves):
+        got = {k: tuple(v.shape) for k, v in
+               W.flatten(abstract["blocks"][i]).items()}
+        if got != want:
+            raise harness.SpecError(f"block {i}: program leaves {got} differ "
+                                    f"from the reference's {want}")
     params = W.nest(W.make(seed, W.TOP, ref.top_leaves(cfg), cfg["dtype"]))
     params["blocks"] = {}
     nm = cfg.get("nm")
-    for i in range(cfg["num_layers"]):
-        flat = W.make(seed, i, ref.block_leaves(cfg), cfg["dtype"])
+    for i, want in enumerate(leaves):
+        flat = W.make(seed, i, want.items(), cfg["dtype"])
         if nm:
             for name in ref.LINEARS:
-                flat[name] = _compress_linear(flat[name], n=nm[0], m=nm[1])
+                if name in flat:
+                    flat[name] = compress(flat[name], *nm)
         params["blocks"][i] = W.nest(flat)
     return model, jax.block_until_ready(params)
 

@@ -6,8 +6,10 @@ calibration set of the traffic file: ``calib_sequences`` sequences of
 ``seq_len`` Zipf-distributed token ids from the seed, in batches of
 ``batch``.  Set-up makes the weights and the calibration set on the device
 and runs one whole call; the window then runs whole calls back to back
-until ``--seconds`` have passed, and the call in flight completes.  One
-call of the window, drawn from the seed, is kept for the check.
+until ``--seconds`` have passed, and the call in flight completes.  A
+call is timed until the device has run all it dispatched: ``prune_model``
+returns with the last block's propagation still queued.  One call of the
+window, drawn from the seed, is kept for the check.
 """
 from __future__ import annotations
 
@@ -41,6 +43,14 @@ def plan(t: dict):
     return PrunePlan(rules=(*rules, PruneRule(match="*", name="skip")))
 
 
+def drain() -> None:
+    """Wait until the device has run everything dispatched so far: a
+    trivial program queued behind it on the device completes only after
+    it.  Without this a call's unfinished tail falls into the next call's
+    time, or, after a call kept for the check, into none."""
+    jax.block_until_ready(jnp.zeros((), jnp.float32) + 1.0)
+
+
 def call_ok(report, t: dict) -> bool:
     """Every linear of the pruned blocks solved cleanly at the cell's
     sparsity, with no guard event."""
@@ -65,8 +75,9 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
     t_setup = time.perf_counter()
     model = build_model(harness.model_config(cfg, num_layers=t["blocks"]))
     params = W.nest(W.make(seed, W.TOP, ref.top_leaves(cfg), cfg["dtype"]))
-    params["blocks"] = {i: W.nest(W.make(seed, i, ref.block_leaves(cfg), cfg["dtype"]))
-                        for i in range(t["blocks"])}
+    params["blocks"] = {
+        i: W.nest(W.make(seed, i, ref.block_leaves(cfg, i), cfg["dtype"]))
+        for i in range(t["blocks"])}
     tokens = calibration(t, cfg, seed)
     batches = [{"tokens": jnp.asarray(tokens[i:i + t["batch"]])}
                for i in range(0, len(tokens), t["batch"])]
@@ -78,6 +89,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
                            on_singular=t["on_singular"])
 
     _, report = one_call()
+    drain()
     warm_ok = call_ok(report, t)
     del report
     rec["setup_s"] = time.perf_counter() - t_setup
@@ -99,6 +111,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
         c_start = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.prune_call"):
             pruned, report = one_call()
+            drain()
         calls.append({"wall_s": time.perf_counter() - c_start,
                       "solve_s": sum(r.seconds for r in report.layers
                                      if not r.skipped)})

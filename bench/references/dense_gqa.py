@@ -25,8 +25,9 @@ HIGHEST = jax.lax.Precision.HIGHEST
 # --------------------------------------------------------------------------
 # parameters
 # --------------------------------------------------------------------------
-def block_leaves(cfg: dict) -> list[tuple[str, tuple[int, ...]]]:
-    """(name, shape) of every weight of one decoder block."""
+def block_leaves(cfg: dict, i: int) -> list[tuple[str, tuple[int, ...]]]:
+    """(name, shape) of every weight of decoder block ``i``; every block of
+    a dense GQA decoder has the same."""
     d, f = cfg["d_model"], cfg["d_ff"]
     q = cfg["num_heads"] * cfg["head_dim"]
     kv = cfg["num_kv_heads"] * cfg["head_dim"]

@@ -6,9 +6,10 @@ same generator.  Every leaf is a pure function of (seed, block, name):
 one jitted call makes a whole block, and calling it again for the
 reference gives the same bits.
 
-Scales: kernels ``N(0, 2/fan_in)`` (stored (in, out)), the embedding
-``N(0, 1)`` so that RMSNorm's epsilon is negligible against the mean
-square, norm scales 1.
+Scales: kernels ``N(0, 2/fan_in)``, stored (in, out) or, for a stack of
+experts, (E, in, out), so that the fan-in is ``shape[-2]`` either way;
+the embedding ``N(0, 1)`` so that RMSNorm's epsilon is negligible against
+the mean square; norm scales 1.
 """
 from __future__ import annotations
 
@@ -27,12 +28,19 @@ def key_data(seed: int) -> np.ndarray:
     return np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
 
 
+def std(name: str, shape) -> float:
+    """The standard deviation leaf ``name`` of ``shape`` is drawn with."""
+    if name.startswith("embed"):
+        return 1.0
+    return (2.0 / shape[-2 if len(shape) >= 2 else 0]) ** 0.5
+
+
 def _leaf(key, name: str, shape, dtype):
     if name.endswith("scale"):
         return jnp.ones(shape, dtype)
-    std = 1.0 if name.startswith("embed") else (2.0 / shape[0]) ** 0.5
     k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-    return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+    return (jax.random.normal(k, shape, jnp.float32)
+            * std(name, shape)).astype(dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("leaves", "dtype"))
